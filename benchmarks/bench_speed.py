@@ -125,7 +125,7 @@ def run(batch: int = 2048, seed: int = 0, tcfg=QUICK, iters: int = 3,
     sys_rows["emulator_conditioned"] = dt * 1e6
     # the unified serving dispatcher, jitted: ONE fused pallas_call per
     # matmul on TPU (both rails + both GEMM stages + scenario epilogue);
-    # on non-TPU hosts the dispatcher's identical-math XLA schedule runs
+    # on non-TPU hosts the dispatcher's same-math XLA schedule runs
     # instead (interpret-mode kernel timings would benchmark the
     # interpreter, not the kernel), so there the row tracks the jitted
     # fast path and the gate is a no-regression check on the dispatcher.

@@ -151,9 +151,9 @@ class _StateBinding:
     (``models.common.use_scan_states``).  Sites inside scan group ``g``,
     period ``p`` are keyed ``"{g}.{p}:{tag}#{j}"`` with the ordinal ``j``
     counted within the period (``scan_record``); at serve time
-    ``scan_xs`` stacks the per-period states onto a leading layer axis so
-    the scan body receives each period's states as TRACED xs slices
-    (``scan_slice``), and ``intercept`` resolves sites from the slice --
+    ``scan_pick`` selects the current period's states by the traced
+    period index the scan carries (``scan_slice``), and ``intercept``
+    resolves sites from that selection --
     the traced weight slice takes the executor's eager in-trace path, so
     the whole scan stays inside ONE compiled serving step."""
 
@@ -195,11 +195,13 @@ class _StateBinding:
         per-period state slice ``ls`` (keyed by within-period site key)."""
         return self._scoped(f"{group}.?:", ls)
 
-    def scan_xs(self, group: str, n: int):
-        """Stack the bound states of scan group ``group`` onto a leading
-        layer axis: ``{inner_site_key: DeploymentState}`` with every leaf
-        ``(n, ...)`` -- ready to ride ``lax.scan`` as xs.  Returns None
-        when the group has no bound states (digital scan layers)."""
+    def scan_pick(self, group: str, n: int):
+        """``pick(i)``: the bound states of period ``i`` of scan group
+        ``group`` as ``{inner_site_key: DeploymentState}``, selected by a
+        ``lax.switch`` on the traced period index -- only the current
+        period's states are copied, never a stack of all of them.
+        Returns None when the group has no bound states (digital scan
+        layers)."""
         if self.states is None:
             return None
         pre = f"{group}."
@@ -218,8 +220,8 @@ class _StateBinding:
                 f"across the {n} periods (bound: {sorted(self.states)}); "
                 "a saved deployment must be served with the model / "
                 "layer configuration it was saved from")
-        return {k: jax.tree.map(lambda *ls: jnp.stack(ls),
-                                *[d[k] for d in per]) for k in keys}
+        branches = [lambda d=d: {k: d[k] for k in keys} for d in per]
+        return lambda i: jax.lax.switch(i, branches)
 
     def intercept(self, ex: "AnalogExecutor", x, w, tag: str):
         sk = self.site_key(tag)
@@ -615,6 +617,13 @@ class AnalogExecutor:
         # re-tiled inside the compiled graph on every call
         with jax.ensure_compile_time_eval():
             plan = build_conductance_plan(w, self.acfg, self.geom)
+            if self.mesh is not None:
+                # sharded like the states made from it: at full width the
+                # plans of every site do not fit on one device
+                from jax.sharding import NamedSharding
+                spec = state_pspecs(self._scheme_for(plan.NB, plan.NO))["gf"]
+                plan = plan.with_g(jax.device_put(
+                    plan.g_feat, NamedSharding(self.mesh, spec)), self.acfg)
         if tag:
             self._plans[tag] = (w, plan)
             self._g0_cache.pop(tag, None)
@@ -638,9 +647,10 @@ class AnalogExecutor:
         return self._aux
 
     def _pre_for(self, plan: ConductancePlan, tag: str, aux: dict) -> dict:
-        """Batch-independent fast-path tensors (zero-voltage block response
-        and its stage-1 projection), cached per (tag, plan)."""
-        if _is_tracer(plan.g_norm) or any(_is_tracer(v) for v in aux.values()
+        """Batch-independent tensors of the XLA fast path (zero-voltage
+        block response and its stage-1 projection), cached per (tag,
+        plan).  The Pallas kernel derives them in VMEM instead."""
+        if _is_tracer(plan.g_feat) or any(_is_tracer(v) for v in aux.values()
                                           if isinstance(v, jax.Array)):
             return conv4xbar.blocklast_precompute(aux, plan.g_norm)
         ent = self._g0_cache.get(tag) if tag else None
@@ -864,14 +874,13 @@ class AnalogExecutor:
             def body(u, pos, gf, ep, *sh):
                 lp = plan.with_lattice(gf, self.acfg, NB=nb_l, NO=no_l)
                 laux = conv4xbar.blocklast_weights(ep, self.geom)
-                lpre = conv4xbar.blocklast_precompute(laux, lp.g_norm)
                 s = sh[0] if sh else None
                 if s is not None and s.ndim == 3:
                     # per-tile shift: the spec sliced this shard's own
                     # (nb_l, no_l) lattice window; flatten to block order
                     s = s.reshape(-1, s.shape[-1])
                 y2 = emulator_block_unified(
-                    laux, lpre, u, pos, shift=s,
+                    laux, lp.g_norm, u, pos, shift=s,
                     use_pallas=self.use_pallas, chunk=self.fast_chunk,
                     tune=False)
                 Ml = u.shape[0]
@@ -989,9 +998,11 @@ class AnalogExecutor:
             return self._sharded_matmul(x2d, x_scale, plan, tag,
                                         eparams, sfeat), x_scale
         if self.acfg.backend == "emulator" and self.fast_path:
-            from repro.kernels.emulator_block import emulator_block_unified
+            from repro.kernels.emulator_block.ops import (
+                emulator_block_unified, runs_kernel)
             aux = self._blocklast_aux(eparams)
-            pre = self._pre_for(plan, tag, aux)
+            pre = (None if runs_kernel(self.use_pallas)
+                   else self._pre_for(plan, tag, aux))
             shift = None
             if sfeat is not None and "f0_scen" in aux:
                 # conditioned corner contribution: a (fc0_out,) bias
@@ -1003,7 +1014,8 @@ class AnalogExecutor:
                     shift = shift.reshape(-1, shift.shape[-1])
             u = plan.tile_v(self._drive01(jnp.abs(x2d) / x_scale), 1.0)
             pos = plan.tile_v((x2d > 0).astype(jnp.float32), 1.0)
-            y2 = emulator_block_unified(aux, pre, u, pos, shift=shift,
+            y2 = emulator_block_unified(aux, plan.g_norm, u, pos,
+                                        shift=shift, pre=pre,
                                         use_pallas=self.use_pallas,
                                         chunk=self.fast_chunk)
             return plan.assemble(y2[0]) - plan.assemble(y2[1]), x_scale

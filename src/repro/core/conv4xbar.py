@@ -255,8 +255,7 @@ def blocklast_precompute(aux: dict, g_norm: jax.Array) -> dict:
 
 
 def _tail_stages(aux: dict, h: jax.Array, n: int, shp,
-                 fc0_shift: jax.Array | None = None,
-                 dot=None) -> jax.Array:
+                 fc0_shift: jax.Array | None = None) -> jax.Array:
     """Conv stages 2.. + FC head on channels-last rows.  h: 2-D (rows, C)
     laid out as shp=(n, D, W, G) x channels; -> (n, O).  ``fc0_shift`` is
     an optional per-call bias shift on fc0's pre-activation (the
@@ -264,23 +263,19 @@ def _tail_stages(aux: dict, h: jax.Array, n: int, shp,
     ``(fc0_out,)`` vector (whole-plan corner) or a per-tile ``(nblk,
     fc0_out)`` lattice -- rows are laid out block-innermost (NB*NO cycles
     fastest), so a 2-D shift folds onto ``(n // nblk, nblk, fc0_out)``
-    and each block gets its own scenario contribution.  ``dot``
-    overrides the contraction (the unified Pallas kernel passes its
-    MXU/bf16 dot so this exact code runs inside the kernel body)."""
-    if dot is None:
-        dot = jnp.matmul
+    and each block gets its own scenario contribution."""
     for wk, b, k in aux["hstages"][1:]:
         # one flat GEMM over (k*C) -- batched matmuls over small trailing
         # matrices are pathologically slow on CPU backends
-        h = jax.nn.celu(dot(h.reshape(-1, wk.shape[0]), wk) + b)
+        h = jax.nn.celu(jnp.matmul(h.reshape(-1, wk.shape[0]), wk) + b)
         shp = shp[:3] + (shp[3] // k,)
     wk, b, kw = aux["wstage"]
     h = h.reshape(shp + (-1,)).transpose(0, 1, 3, 2, 4)   # (n, D, H, W, C)
-    h = jax.nn.celu(dot(h.reshape(-1, wk.shape[0]), wk) + b)
+    h = jax.nn.celu(jnp.matmul(h.reshape(-1, wk.shape[0]), wk) + b)
     h = h.reshape(n, -1)                              # (d, h, w, c) flatten
     fcs = aux["fcs"]
     for i, (fw, fb) in enumerate(fcs):
-        h = dot(h, fw) + fb
+        h = jnp.matmul(h, fw) + fb
         if i == 0 and fc0_shift is not None:
             if fc0_shift.ndim == 2:
                 nblk, f = fc0_shift.shape
@@ -292,7 +287,7 @@ def _tail_stages(aux: dict, h: jax.Array, n: int, shp,
     return h
 
 
-def dual_rail_stage1(g0k, celu0k, y0, w0v, w1k, u, pos, dot=None):
+def dual_rail_stage1(g0k, celu0k, y0, w0v, w1k, u, pos):
     """Stage 0+1 of the single-pass dual-rail factorization.
 
     u, pos: (..., G, k1) magnitude drive / positive-rail mask, with the
@@ -302,21 +297,19 @@ def dual_rail_stage1(g0k, celu0k, y0, w0v, w1k, u, pos, dot=None):
     rails' stage-1 pre-activations ``(y0 + t_pos, y0 + t_full - t_pos)``
     stacked: (2, batch, R, O1).
 
-    Shared verbatim by ``apply_blocklast`` (CPU/XLA path) and the unified
-    Pallas kernel body, so the two paths are bit-identical by
-    construction: per window position kk, delta_kk = celu(v0 + g0) -
-    celu(g0) is contracted over channels only (one (C0, O1) GEMM) and the
-    rail mask lands on the GEMM *output* -- half the FLOPs of the old
-    cross-position (C0, k1*O1) contraction, and no diagonal gather."""
-    if dot is None:
-        dot = jnp.matmul
+    Per window position kk, delta_kk = celu(v0 + g0) - celu(g0) is
+    contracted over channels only (one (C0, O1) GEMM) and the rail mask
+    lands on the GEMM *output* -- half the FLOPs of a cross-position
+    (C0, k1*O1) contraction, and no diagonal gather.  The unified Pallas
+    kernel evaluates the same factorization in its own 2-d layout
+    (``kernels.emulator_block``), equal to f32 rounding."""
     k1, C0, O1 = w1k.shape
     R = y0.shape[0]
     t_full = t_pos = None
     for kk in range(k1):
         v0 = u[..., kk, None] * w0v                   # broadcasts vs g0k[kk]
         delta = jax.nn.celu(v0 + g0k[kk]) - celu0k[kk]
-        t = dot(delta.reshape(-1, C0), w1k[kk])
+        t = jnp.matmul(delta.reshape(-1, C0), w1k[kk])
         t = t.reshape(-1, R, O1)                      # (batch, R, O1)
         m = jnp.broadcast_to(pos[..., kk, None], delta.shape[:-1] + (1,))
         m = m.reshape(-1, R, 1)

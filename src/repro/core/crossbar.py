@@ -138,8 +138,16 @@ class ConductancePlan:
     NO: int                       # output groups over N
     no: int                       # outputs per block
     g_feat: jax.Array             # (NB, NO, D, H, W=2*no) raw conductances [S]
-    g_norm: jax.Array             # same, normalized to [0, 1] for the emulator
+    g_range: Tuple[float, float]  # (g_min, g_max) of the normalization
     out_perm: Optional[jax.Array] = None   # (N,) logical col -> physical col
+
+    @property
+    def g_norm(self) -> jax.Array:
+        """``g_feat`` normalized to [0, 1] for the emulator.  Derived on
+        use, not stored: a full-width plan cache would otherwise hold
+        every site's conductances twice on the device."""
+        g_min, g_max = self.g_range
+        return (self.g_feat - g_min) / (g_max - g_min)
 
     @property
     def n_blocks(self) -> int:
@@ -163,11 +171,10 @@ class ConductancePlan:
         to slicing the full computation.  The output permutation is
         dropped: the fault-remap gather runs on the full post-psum
         output, never on a shard-local slice."""
-        g_norm = (g_feat - acfg.g_min) / (acfg.g_max - acfg.g_min)
         return dataclasses.replace(
             self, NB=self.NB if NB is None else NB,
             NO=self.NO if NO is None else NO,
-            g_feat=g_feat, g_norm=g_norm, out_perm=None)
+            g_feat=g_feat, g_range=(acfg.g_min, acfg.g_max), out_perm=None)
 
     def with_g(self, g_feat: jax.Array, acfg: AnalogConfig) -> "ConductancePlan":
         """Same block layout, different conductances (repro.nonideal injects
@@ -176,8 +183,8 @@ class ConductancePlan:
         perturbation.  Static fields are unchanged, so compiled functions
         built for this plan's shapes are reused when g_feat is a traced
         argument."""
-        g_norm = (g_feat - acfg.g_min) / (acfg.g_max - acfg.g_min)
-        return dataclasses.replace(self, g_feat=g_feat, g_norm=g_norm)
+        return dataclasses.replace(self, g_feat=g_feat,
+                                   g_range=(acfg.g_min, acfg.g_max))
 
     def tile_v(self, v01: jax.Array, v_read: float) -> jax.Array:
         """(M, K) wordline drive in [0,1] -> (M, NB, D, H) tile voltages."""
@@ -235,9 +242,8 @@ def build_conductance_plan(w: jax.Array, acfg: AnalogConfig,
     gnb = gn.reshape(NB, D, H, NO, no)
     g = jnp.stack([gpb, gnb], axis=-1).reshape(NB, D, H, NO, 2 * no)
     g_feat = g.transpose(0, 3, 1, 2, 4)               # (NB, NO, D, H, W)
-    g_norm = (g_feat - acfg.g_min) / (acfg.g_max - acfg.g_min)
     return ConductancePlan(K=K, N=N, rows=H, D=D, NB=NB, NO=NO, no=no,
-                           g_feat=g_feat, g_norm=g_norm)
+                           g_feat=g_feat, g_range=(acfg.g_min, acfg.g_max))
 
 
 # --------------------------------------------------------------------------- #

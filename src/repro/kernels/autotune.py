@@ -24,9 +24,9 @@ With tuning disabled every call resolves to the caller's default, so
 the kernels behave like the old hardcoded constants.
 
 ``best_config`` may be consulted from inside a ``jit`` trace: the key is
-shape-derived (static under tracing) and the measure closure runs on
-concrete dummy operands, so a cache miss sweeps eagerly at trace time
-and the chosen config is baked into the executable being built.
+shape-derived (static under tracing).  Callers under a trace pass
+``measure=None`` -- a sweep there would time the tracing of nested jits,
+not the kernel -- so a traced resolution is a cache hit or the default.
 
 Every resolution is recorded (``report()``) so benchmark runs can write
 the chosen block sizes and the cache-hit status into their artifact
@@ -136,10 +136,11 @@ def best_config(kernel: str, key_parts: Sequence, candidates: List[dict],
     """Resolve the config for one (kernel, backend, shape) combination.
 
     ``measure(cfg)`` runs the kernel once under ``cfg`` (it is invoked
-    repeatedly and timed here); candidates that raise are skipped, so an
-    over-sized block that fails to compile just loses the sweep.  With
-    tuning disabled or no ``measure``, ``default`` is returned
-    unconditionally (and recorded as such).
+    repeatedly and timed here).  A candidate that raises -- a kernel the
+    compiler refuses -- propagates: the candidate lists are meant to hold
+    only configurations that compile, and a silently lost candidate would
+    hide the failure.  With tuning disabled or no ``measure``, ``default``
+    is returned unconditionally (and recorded as such).
     """
     key = _key(kernel, key_parts)
     if key in _MEM:
@@ -156,10 +157,7 @@ def best_config(kernel: str, key_parts: Sequence, candidates: List[dict],
     t_sweep = time.perf_counter()
     best, best_t = default, float("inf")
     for cfg in candidates:
-        try:
-            t = _measure_median(measure, cfg)
-        except Exception:         # noqa: BLE001 -- losing candidates is fine
-            continue
+        t = _measure_median(measure, cfg)
         if t < best_t:
             best, best_t = cfg, t
     if OBS.enabled:

@@ -13,14 +13,13 @@ Tiling: grid (N / bn); every stage is a dot over (C_in x k) contractions.
 from __future__ import annotations
 
 import functools
-from typing import List, Tuple
+from typing import List
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.conv4xbar import (ConvStage, _tail_stages, conv_out_sizes,
-                                  dual_rail_stage1)
+from repro.core.conv4xbar import ConvStage, conv_out_sizes
 
 
 def _stage_apply(h, w, b, st: ConvStage):
@@ -221,155 +220,231 @@ def emulator_block_pallas(params: dict, x: jax.Array, periph: jax.Array,
 # --------------------------------------------------------------------------- #
 # THE unified serving kernel: one pallas_call for every device corner
 # --------------------------------------------------------------------------- #
-def _unified_kernel(*refs, tail_ks: Tuple[int, ...], kw: int, n_fc: int,
-                    out_dtype, compute_dtype):
-    """Grid step (batch tile i, crossbar block j): BOTH rails of the
-    dual-rail delta factorization and BOTH GEMM stages (stage-1 window
-    contraction + tail conv/FC stack), evaluated in VMEM.
+def _celu(x):
+    """CELU (alpha 1) through ``exp``: Mosaic has no ``expm1`` lowering,
+    so the negative branch carries an absolute error of about one f32
+    ulp of 1 where ``jax.nn.celu`` is relative-exact."""
+    return jnp.maximum(x, 0.0) + (jnp.exp(jnp.minimum(x, 0.0)) - 1.0)
 
-    The kernel body calls the same ``dual_rail_stage1``/``_tail_stages``
-    code the CPU fast path (``conv4xbar.apply_blocklast``) runs, so the
-    two paths are bit-identical by construction.  The scenario epilogue
-    is the precomputed fc0 shift ``sfeat @ f0_scen`` -- grid-constant
-    for a whole-plan corner, block-indexed ``(1, fc0_out)`` for per-tile
-    feature operands, exactly zero at the ideal corner's all-zero
-    encoding -- so
-    ONE compiled kernel serves ideal, conditioned and non-ideal corners
-    (perturbed conductances arrive through the block-indexed g0/celu0/y0
-    precompute operands).  ``compute_dtype=bfloat16`` runs every GEMM
-    with bf16 operands and f32 accumulation (MXU-native); f32 keeps the
-    parity-exact contraction."""
-    (u_ref, pos_ref, g0_ref, c0_ref, y0_ref, sh_ref, w0v_ref,
-     w1k_ref) = refs[:8]
-    idx = 8
+
+def _exact_dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    """f32 contraction at full precision (on the TPU's MXU a default-
+    precision f32 dot rounds its operands to bf16)."""
+    return jax.lax.dot_general(a, b, dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+_NT = (((1,), (1,)), ((), ()))             # a @ b.T
+
+
+def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
+                    n_tail: int, n_fc: int, compute_dtype):
+    """Grid step (block tile j, batch row m): BOTH rails of the dual-rail
+    delta factorization and the whole conv/FC stack for ``bn`` crossbar
+    blocks, in VMEM.
+
+    Layout: blocks ride the sublane dim, and each (window position kk,
+    tile d, bitline w) piece of a block keeps its row groups and channels
+    flattened in the lanes, ``(g, c)``.  Every stage is then a 2-d
+    contraction against a block-diagonal ``kron(I, w)`` weight built by
+    the wrapper, and every slice is a static index of a ref's leading
+    dim -- the forms Mosaic lowers.  The stage-0 precompute (``g0``, its
+    zero-voltage response and stage-1 projection) is evaluated here from
+    the normalized conductances rather than read from HBM."""
+    (u_ref, pos_ref, gn_ref, sh_ref, ev_ref, eg_ref, b0_ref, w1_ref, b1_ref,
+     em_ref) = refs[:10]
+    idx = 10
     tail = []
-    for k in tail_ks:
-        tail.append((refs[idx][...].astype(jnp.float32),
-                     refs[idx + 1][...].astype(jnp.float32), k))
+    for _ in range(n_tail):
+        tail.append((refs[idx], refs[idx + 1]))
         idx += 2
-    wstage = (refs[idx][...].astype(jnp.float32),
-              refs[idx + 1][...].astype(jnp.float32), kw)
-    idx += 2
+    ws_ref, wsb_ref, f0_ref, f0b_ref = refs[idx:idx + 4]
+    idx += 4
     fcs = []
-    for _ in range(n_fc):
-        fcs.append((refs[idx][...].astype(jnp.float32),
-                    refs[idx + 1][...].astype(jnp.float32)))
+    for _ in range(n_fc - 1):
+        fcs.append((refs[idx], refs[idx + 1]))
         idx += 2
     o_ref = refs[idx]
-
-    u = u_ref[...].astype(jnp.float32)                # (bm, 1, D, G, k1)
-    pos = pos_ref[...].astype(jnp.float32)
-    bm, _, D, G, k1 = u.shape
-    g0k = g0_ref[...].astype(jnp.float32)[0]          # (k1, D, W, G, C0)
-    celu0k = c0_ref[...].astype(jnp.float32)[0]
-    W = g0k.shape[2]
-    y0 = y0_ref[...].astype(jnp.float32)[0]           # (D*W*G, O1)
-    shift = sh_ref[...].astype(jnp.float32)
-    w0v = w0v_ref[...].astype(jnp.float32)
-    w1k = w1k_ref[...].astype(jnp.float32)
+    n_out = o_ref.shape[2] // 2
 
     if compute_dtype == jnp.float32:
-        dot = None                # jnp.matmul -- identical to the CPU path
+        gemm = _exact_dot
     else:
-        def dot(a, b):
-            return jnp.dot(a.astype(compute_dtype), b.astype(compute_dtype),
-                           preferred_element_type=jnp.float32)
+        def gemm(a, b, dims=(((1,), (0,)), ((), ()))):
+            return jax.lax.dot_general(
+                a.astype(compute_dtype), b.astype(compute_dtype), dims,
+                preferred_element_type=jnp.float32)
 
-    # singleton W axis so the per-kk drive broadcasts against g0k[kk]
-    ub = u.reshape(bm, D, 1, G, k1)
-    pb = pos.reshape(bm, D, 1, G, k1)
-    h = jax.nn.celu(dual_rail_stage1(g0k, celu0k, y0, w0v, w1k, ub, pb,
-                                     dot=dot))        # (2, bm, D*W*G, O1)
-    n2 = 2 * bm
-    aux_k = {"hstages": ((None, None, k1),) + tuple(tail),
-             "wstage": wstage, "fcs": tuple(fcs)}
-    h = _tail_stages(aux_k, h.reshape(n2, -1), n2, (n2, D, W, G),
-                     fc0_shift=shift, dot=dot)
-    o_ref[...] = h.reshape(2, bm, 1, -1).astype(out_dtype)
+    ev, eg, em = ev_ref[...], eg_ref[...], em_ref[...]
+    b0, b1 = b0_ref[...], b1_ref[...]
+    w1 = [w1_ref[kk] for kk in range(k1)]
+    wo_n = W // kw
+
+    def tile(d, accs):
+        # the wordline drive (one batch row) and the positive-rail mask,
+        # expanded from row groups g to the (g, c) lanes of each piece
+        rows = [pl.ds(kk * D + d, 1) for kk in range(k1)]
+        v0 = [_exact_dot(u_ref[0, 0, r, :], ev) for r in rows]
+        mk = [_exact_dot(pos_ref[0, 0, r, :], em) for r in rows]
+        h = [[], []]                       # rail -> [bitline w]
+        for w in range(W):
+            y0, t_full, t_pos = b1, None, None
+            for kk in range(k1):
+                lanes = pl.ds((kk * W + w) * G, G)
+                g0 = _exact_dot(gn_ref[d, :, lanes], eg) + b0
+                c0 = _celu(g0)
+                y0 = y0 + _exact_dot(c0, w1[kk])
+                t = gemm(_celu(v0[kk] + g0) - c0, w1[kk])
+                t_full = t if t_full is None else t_full + t
+                tp = t * mk[kk]
+                t_pos = tp if t_pos is None else t_pos + tp
+            for r, pre in enumerate((y0 + t_pos, y0 + t_full - t_pos)):
+                x = _celu(pre)
+                for wk_ref, bk_ref in tail:
+                    x = _celu(gemm(x, wk_ref[...]) + bk_ref[...])
+                h[r].append(x)
+        # bitline-pair stage, then this tile's rows of fc0
+        out = []
+        for r in range(2):
+            acc = accs[r]
+            for wo in range(wo_n):
+                s = wsb_ref[...]
+                for j in range(kw):
+                    s = s + gemm(h[r][wo * kw + j], ws_ref[j])
+                acc = acc + gemm(_celu(s), f0_ref[d * wo_n + wo])
+            out.append(acc)
+        return tuple(out)
+
+    acc0 = jnp.broadcast_to(f0b_ref[...] + sh_ref[...],
+                            (gn_ref.shape[1], f0b_ref.shape[1]))
+    accs = jax.lax.fori_loop(0, D, tile, (acc0, acc0))
+    for r in range(2):
+        x = accs[r]
+        for fw_ref, fb_ref in fcs[:-1]:
+            x = gemm(_celu(x), fw_ref[...]) + fb_ref[...]
+        # the last layer is contracted transposed, (n_out, bn): blocks
+        # land in the lanes, so the output is not padded to 128 lanes
+        fw_ref, fb_ref = fcs[-1]
+        y = gemm(fw_ref[...], _celu(x), _NT) + fb_ref[...]
+        o_ref[0, 0, r * n_out:(r + 1) * n_out, :] = y.astype(o_ref.dtype)
 
 
 def _const_spec(arr):
     return pl.BlockSpec(arr.shape, lambda *_, nd=arr.ndim: (0,) * nd)
 
 
-def emulator_block_unified_pallas(aux: dict, pre: dict, u01: jax.Array,
-                                  pos01: jax.Array, *,
+def _kron_eye(n: int, w: jax.Array) -> jax.Array:
+    """Block-diagonal ``kron(I_n, w)``: one copy of ``w`` per row group."""
+    return jnp.kron(jnp.eye(n, dtype=jnp.float32), w.astype(jnp.float32))
+
+
+def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
+                                  u01: jax.Array, pos01: jax.Array, *,
                                   shift: jax.Array | None = None,
-                                  block_m: int = 128,
+                                  block_n: int = 128,
                                   interpret: bool = False,
                                   compute_dtype=jnp.float32) -> jax.Array:
     """One kernel launch per matmul, every corner on the TPU path.
 
-    aux/pre: ``conv4xbar.blocklast_weights`` / ``blocklast_precompute``
-    tensors (the precompute carries the deployed -- possibly perturbed --
-    conductance state); u01/pos01: (M, NB, D, H) magnitude drive and
-    positive-rail mask; shift: optional scenario epilogue
-    ``sfeat @ aux["f0_scen"]`` -- ``(fc0_out,)`` grid-constant for a
-    whole-plan corner, or ``(NB*NO, fc0_out)`` block-indexed for
-    per-tile feature operands (each grid cell then reads its own tile's
-    shift) -- None = ideal, folds to an exact zero add.
+    aux: ``conv4xbar.blocklast_weights`` tensors; g_norm: (NB, NO, D, H, W)
+    normalized -- deployed, possibly perturbed -- conductances; u01/pos01:
+    (M, NB, D, H) magnitude drive and positive-rail mask; shift: optional
+    scenario epilogue ``sfeat @ aux["f0_scen"]`` -- ``(fc0_out,)``
+    grid-constant for a whole-plan corner, or ``(NB*NO, fc0_out)``
+    block-indexed for per-tile feature operands -- None = ideal, an exact
+    zero add.  ``block_n`` crossbar blocks share one grid step (rounded
+    to a multiple of 8; the output-group axis is zero-padded to a whole
+    number of tiles and sliced back).
+
+    Numerics: the same math as ``conv4xbar.apply_blocklast``, associated
+    differently -- the stage-1 projection of the zero-voltage response
+    is summed per window position, and the conv/FC stack runs as
+    block-diagonal contractions -- so the two agree to f32 rounding, not
+    bitwise (tests/test_kernels.py states the tolerance).  f32 GEMMs run
+    at ``Precision.HIGHEST``; ``compute_dtype=bfloat16`` runs the GEMM
+    stages with bf16 operands and f32 accumulation, while the drive and
+    conductance expansions and the zero-voltage projection stay exact.
     Returns (2, M*NB*NO, O) rail block outputs, row-compatible with
     ``apply_blocklast``."""
     M, NB, D, H = u01.shape
-    g0k = pre["g0k"]                                  # (k1,NB,NO,D,W,G,C0)
-    k1, _, NO, _, W, G, C0 = g0k.shape
-    NBLK = NB * NO
+    _, NO, _, _, W = g_norm.shape
     w1k = aux["w1k"]
-    O1 = w1k.shape[2]
+    k1, C0, O1 = w1k.shape
+    G = H // k1
     fcs = aux["fcs"]
     n_fc = len(fcs)
     n_out = fcs[-1][0].shape[1]
-    if shift is None:
-        shift = jnp.zeros((fcs[0][0].shape[1],), jnp.float32)
-
-    bm = min(block_m, M)
-    padM = (-M) % bm
-    ug = u01.reshape(M, NB, D, G, k1)
-    pg = pos01.reshape(M, NB, D, G, k1)
-    if padM:
-        ug = jnp.pad(ug, ((0, padM),) + ((0, 0),) * 4)
-        pg = jnp.pad(pg, ((0, padM),) + ((0, 0),) * 4)
-    Mp = M + padM
-    g0b = g0k.transpose(1, 2, 0, 3, 4, 5, 6).reshape(NBLK, k1, D, W, G, C0)
-    c0b = pre["celu0k"].transpose(1, 2, 0, 3, 4, 5, 6).reshape(
-        NBLK, k1, D, W, G, C0)
-    y0b = pre["y0"].reshape(NBLK, D * W * G, O1)
-
-    tail = aux["hstages"][1:]
+    F0 = fcs[0][0].shape[1]
     wst_w, wst_b, kw = aux["wstage"]
-    operands = [ug, pg, g0b, c0b, y0b, shift, aux["w0v"], w1k]
+    Cw = wst_w.shape[1]
+    wo_n = W // kw
+
+    bn = -(-min(block_n, NO) // 8) * 8
+    NOp = -(-NO // bn) * bn
+    nbt = NOp // bn                                   # block tiles per nb
+
+    # tiles d lead, blocks next, (kk, w, g) in the lanes
+    gn = g_norm.astype(jnp.float32).reshape(NB, NO, D, G, k1, W)
+    if NOp != NO:
+        gn = jnp.pad(gn, ((0, 0), (0, NOp - NO)) + ((0, 0),) * 4)
+    gn = gn.transpose(2, 0, 1, 4, 5, 3).reshape(D, NB * NOp, k1 * W * G)
+
+    def drive(a):                                     # -> (M, NB, k1*D, G)
+        a = a.astype(jnp.float32).reshape(M, NB, D, G, k1)
+        return a.transpose(0, 1, 4, 2, 3).reshape(M, NB, k1 * D, G)
+
+    tiled = shift is not None and shift.ndim == 2
+    if shift is None:
+        shift = jnp.zeros((1, F0), jnp.float32)
+    elif shift.ndim == 1:
+        shift = shift.reshape(1, F0)
+    else:
+        shift = shift.reshape(NB, NO, F0)
+        if NOp != NO:
+            shift = jnp.pad(shift, ((0, 0), (0, NOp - NO), (0, 0)))
+        shift = shift.reshape(NB * NOp, F0)
+
+    f32 = lambda a: a.astype(jnp.float32)
+    ev = _kron_eye(G, aux["w0v"][None])              # (G, G*C0)
+    eg = _kron_eye(G, aux["w0g"][None])
+    b0 = jnp.tile(f32(aux["b0"]), G)[None]           # (1, G*C0)
+    w1big = jnp.stack([_kron_eye(G, w1k[kk]) for kk in range(k1)])
+    b1 = jnp.tile(f32(aux["hstages"][0][1]), G)[None]
+    em = _kron_eye(G, jnp.ones((1, O1), jnp.float32))  # mask -> (g, o1)
+    consts = [ev, eg, b0, w1big, b1, em]
+    g = G
+    for wk, b, k in aux["hstages"][1:]:
+        g //= k
+        consts += [_kron_eye(g, wk), jnp.tile(f32(b), g)[None]]
+    assert g == 1, "row stack must reduce every wordline group"
+    consts += [f32(wst_w).reshape(kw, -1, Cw), f32(wst_b)[None],
+               f32(fcs[0][0]).reshape(D * wo_n, Cw, F0), f32(fcs[0][1])[None]]
+    for fw, fb in fcs[1:-1]:
+        consts += [f32(fw), f32(fb)[None]]
+    consts += [f32(fcs[-1][0]).T, f32(fcs[-1][1])[:, None]]
+
+    blk = lambda j, m: (m, j // nbt, 0, 0)
     in_specs = [
-        pl.BlockSpec((bm, 1, D, G, k1), lambda i, j: (i, j // NO, 0, 0, 0)),
-        pl.BlockSpec((bm, 1, D, G, k1), lambda i, j: (i, j // NO, 0, 0, 0)),
-        pl.BlockSpec((1, k1, D, W, G, C0),
-                     lambda i, j: (j, 0, 0, 0, 0, 0)),
-        pl.BlockSpec((1, k1, D, W, G, C0),
-                     lambda i, j: (j, 0, 0, 0, 0, 0)),
-        pl.BlockSpec((1, D * W * G, O1), lambda i, j: (j, 0, 0)),
-        # per-tile (NBLK, fc0_out) shift: each grid cell j reads row j;
-        # whole-plan (fc0_out,) shift: grid-constant
-        (pl.BlockSpec((1, shift.shape[1]), lambda i, j: (j, 0))
-         if shift.ndim == 2 else _const_spec(shift)),
-        _const_spec(aux["w0v"]), _const_spec(w1k),
-    ]
-    for wk, b, _ in tail:
-        operands += [wk, b]
-        in_specs += [_const_spec(wk), _const_spec(b)]
-    operands += [wst_w, wst_b]
-    in_specs += [_const_spec(wst_w), _const_spec(wst_b)]
-    for fw, fb in fcs:
-        operands += [fw, fb]
-        in_specs += [_const_spec(fw), _const_spec(fb)]
+        pl.BlockSpec((1, 1, k1 * D, G), blk),
+        pl.BlockSpec((1, 1, k1 * D, G), blk),
+        pl.BlockSpec((D, bn, k1 * W * G), lambda j, m: (0, j, 0)),
+        (pl.BlockSpec((bn, F0), lambda j, m: (j, 0))
+         if tiled else _const_spec(shift)),
+    ] + [_const_spec(c) for c in consts]
 
     out = pl.pallas_call(
-        functools.partial(_unified_kernel,
-                          tail_ks=tuple(k for _, _, k in tail), kw=kw,
-                          n_fc=n_fc, out_dtype=jnp.float32,
+        functools.partial(_unified_kernel, D=D, W=W, G=G, k1=k1, kw=kw,
+                          n_tail=len(aux["hstages"]) - 1, n_fc=n_fc,
                           compute_dtype=compute_dtype),
-        grid=(Mp // bm, NBLK),
+        grid=(NB * nbt, M),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((2, bm, 1, n_out), lambda i, j: (0, i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((2, Mp, NBLK, n_out), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, 2 * n_out, bn),
+                               lambda j, m: (m, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((M, NB * nbt, 2 * n_out, bn),
+                                       jnp.float32),
         interpret=interpret,
-    )(*operands)
-    return out[:, :M].reshape(2, M * NBLK, n_out)
+    )(drive(u01), drive(pos01), gn, f32(shift), *consts)
+    out = out.reshape(M, NB, nbt, 2, n_out, bn).transpose(3, 0, 1, 2, 5, 4)
+    out = out.reshape(2, M, NB, NOp, n_out)[:, :, :, :NO]
+    return out.reshape(2, M * NB * NO, n_out)

@@ -7,7 +7,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.rram_ps32 import BlockGeometry
-from repro.core.conv4xbar import apply_blocklast, build_stages
+from repro.core.conv4xbar import (apply_blocklast, blocklast_precompute,
+                                  build_stages)
 from repro.kernels import autotune
 from repro.kernels.emulator_block.emulator_block import (
     emulator_block_grid_pallas, emulator_block_pallas,
@@ -41,93 +42,103 @@ def emulator_block_grid(params: dict, v01: jax.Array, g_norm: jax.Array,
 
 
 def _dummy_like(tree):
-    """Concrete stand-ins with the tree's shapes/dtypes (leaves may be
-    tracers when the caller is under ``jit``; shapes are static).
-    Non-array leaves (the static kernel widths in aux) pass through."""
+    """Concrete stand-ins with the tree's shapes/dtypes.  Non-array
+    leaves (the static kernel widths in aux) pass through."""
     return jax.tree_util.tree_map(
         lambda a: jnp.full(a.shape, 0.1, a.dtype)
         if hasattr(a, "shape") else a, tree)
 
 
-def emulator_block_unified(aux: dict, pre: dict, u01: jax.Array,
+def _traced(tree) -> bool:
+    return any(isinstance(a, jax.core.Tracer)
+               for a in jax.tree_util.tree_leaves(tree))
+
+
+def runs_kernel(use_pallas: bool | None) -> bool:
+    """Whether ``emulator_block_unified`` takes the Pallas kernel: always
+    on a TPU, elsewhere only when asked (in interpret mode)."""
+    return _on_tpu() or bool(use_pallas)
+
+
+# blocks per grid step the tuner may pick; each compiles for the v5e at
+# the gate/up and down widths of the served models within the default
+# scoped VMEM (tests/test_tpu_compile.py)
+BLOCK_N_CANDIDATES = (128, 256)
+
+
+def emulator_block_unified(aux: dict, g_norm: jax.Array, u01: jax.Array,
                            pos01: jax.Array, *,
                            shift: jax.Array | None = None,
+                           pre: dict | None = None,
                            use_pallas: bool | None = None,
                            chunk: int | None = None,
-                           block_m: int | None = None,
-                           interpret: bool | None = None,
+                           block_n: int | None = None,
                            tune: bool = True,
                            compute_dtype=jnp.float32) -> jax.Array:
     """Single entry point for the emulator serving math, every corner.
 
     Dispatches ONE dual-rail evaluation -- ``shift`` is the precomputed
     scenario epilogue (``sfeat @ aux["f0_scen"]``, None at the ideal
-    corner) -- to either the fused pallas kernel
-    (``emulator_block_unified_pallas``, default on TPU) or the identical
-    chunked XLA evaluation (``conv4xbar.apply_blocklast``, default
-    elsewhere).  Both run the same ``dual_rail_stage1``/``_tail_stages``
-    code, so the choice is a pure scheduling decision: outputs are
-    bit-identical in f32.
+    corner) -- to the fused Pallas kernel
+    (``emulator_block_unified_pallas``) or the chunked XLA evaluation
+    (``conv4xbar.apply_blocklast``, over ``pre``, the
+    ``blocklast_precompute`` of ``g_norm``, derived here when None).
 
-    ``block_m``/``chunk`` left as None are resolved by the autotuner
-    (``kernels.autotune``) when sweeping is enabled, else fall back to
-    heuristic defaults (min(128, M) / 2).  ``tune=False`` skips the
-    autotuner entirely and takes the heuristic defaults directly -- the
-    executor's ``shard_map`` bodies run per-shard lattice slices whose
-    shapes the tuner never measured, and a sweep (timed compiles) must
-    not fire inside a collective trace.  Block-size choice is a pure
-    scheduling decision either way: outputs are bit-identical in f32.
+    On a TPU the kernel always runs compiled: ``use_pallas`` is ignored
+    there, and nothing falls back to the XLA schedule or the
+    interpreter.  Elsewhere ``use_pallas=True`` runs the kernel in
+    interpret mode (tests) and the default is the XLA schedule.  The two
+    agree to f32 rounding (``emulator_block_unified_pallas``).
+
+    ``block_n``/``chunk`` left as None are resolved by the autotuner
+    (``kernels.autotune``): a cache hit, else -- only when every operand
+    is concrete -- a sweep of real compiles, else the heuristic default
+    (128 / 2).  Under an enclosing trace (the serving steps) the sweep
+    never fires: it would time tracing, not the kernel.  ``tune=False``
+    takes the default directly (the executor's ``shard_map`` bodies run
+    per-shard lattice slices the tuner never measured).
     Returns (2, M*NB*NO, O).
     """
     M = u01.shape[0]
-    g0k = pre["g0k"]
-    k1, NB, NO, D, W, G, C0 = g0k.shape
+    NB, NO, D, H, W = g_norm.shape
     n_out = aux["fcs"][-1][0].shape[1]
-    if use_pallas is None:
-        use_pallas = _on_tpu()
+    ops = (aux, g_norm, u01, pos01, shift)
+    on_tpu = _on_tpu()
 
-    if use_pallas:
-        if interpret is None:
-            interpret = not _on_tpu()
-        if block_m is None and not tune:
-            block_m = min(128, M)
-        if block_m is None:
-            key_parts = (M, NB, NO, D, W, G, k1, C0, n_out,
-                         jnp.dtype(compute_dtype).name, interpret)
-            # dummies/jitted fns built lazily INSIDE measure -- it only
-            # runs on a sweep; cache hits must stay a dict lookup
+    if runs_kernel(use_pallas):
+        if block_n is None and tune:
+            key_parts = (M, NB, NO, D, H, W, n_out,
+                         jnp.dtype(compute_dtype).name, not on_tpu)
             state = {}
 
             def measure(cfg):
-                bm = cfg["block_m"]
+                bn = cfg["block_n"]
                 if "dummies" not in state:
-                    state["dummies"] = _dummy_like((aux, pre, u01, pos01,
-                                                    shift))
-                da, dp, du, dpos, dsh = state["dummies"]
-                if bm not in state:
-                    # aux/pre closed over (weights are trace constants in
-                    # serving too); drive tensors traced so nothing folds
-                    state[bm] = jax.jit(
-                        lambda uu, qq, ss, bm=bm:
+                    state["dummies"] = _dummy_like(ops)
+                da, dg, du, dpos, dsh = state["dummies"]
+                if bn not in state:
+                    state[bn] = jax.jit(
+                        lambda gg, uu, qq, ss, bn=bn:
                         emulator_block_unified_pallas(
-                            da, dp, uu, qq, shift=ss, block_m=bm,
-                            interpret=interpret,
+                            da, gg, uu, qq, shift=ss, block_n=bn,
+                            interpret=not on_tpu,
                             compute_dtype=compute_dtype))
-                jax.block_until_ready(state[bm](du, dpos, dsh))
+                jax.block_until_ready(state[bn](dg, du, dpos, dsh))
 
             cfg = autotune.best_config(
                 "emulator_unified", key_parts,
-                [{"block_m": b} for b in (16, 32, 64, 128, 256)],
-                measure, {"block_m": min(128, M)})
-            block_m = cfg["block_m"]
+                [{"block_n": b} for b in BLOCK_N_CANDIDATES],
+                None if _traced(ops) else measure, {"block_n": 128})
+            block_n = cfg["block_n"]
         return emulator_block_unified_pallas(
-            aux, pre, u01, pos01, shift=shift, block_m=block_m,
-            interpret=interpret, compute_dtype=compute_dtype)
+            aux, g_norm, u01, pos01, shift=shift,
+            block_n=128 if block_n is None else block_n,
+            interpret=not on_tpu, compute_dtype=compute_dtype)
 
-    if chunk is None and not tune:
-        chunk = 2
-    if chunk is None:
-        key_parts = (M, NB, NO, D, W, G, k1, C0, n_out)
+    if pre is None:
+        pre = blocklast_precompute(aux, g_norm)
+    if chunk is None and tune:
+        key_parts = (M, NB, NO, D, H, W, n_out)
         state = {}             # lazy dummies + per-config compiled fns
 
         def measure(cfg):
@@ -145,7 +156,8 @@ def emulator_block_unified(aux: dict, pre: dict, u01: jax.Array,
         cfg = autotune.best_config(
             "blocklast_chunk", key_parts,
             [{"chunk": c} for c in (1, 2, 4, 8)],
-            measure, {"chunk": 2})
+            None if _traced(ops + (pre,)) else measure, {"chunk": 2})
         chunk = cfg["chunk"]
-    return apply_blocklast(aux, pre, u01, pos01, chunk=chunk,
+    return apply_blocklast(aux, pre, u01, pos01,
+                           chunk=2 if chunk is None else chunk,
                            fc0_shift=shift)

@@ -219,7 +219,8 @@ class ContinuousBatchEngine:
         cs = M.model_cache_schema(self.cfg, self.max_slots, self.max_len)
         self._cache_schema = cs
 
-        def run_decode(tok, cache, pos, states):
+        # params ride as arguments, not as executable constants
+        def run_decode(params, tok, cache, pos, states):
             self.decode_traces += 1             # trace-time side effect
             if OBS.enabled:
                 OBS.counter("serve_traces_total",
@@ -227,10 +228,9 @@ class ContinuousBatchEngine:
                             "sweep holds this at 1 per step)",
                             site=self.site, step="batch_decode").inc()
             with self.session._bound(states):
-                return self.session._decode_step(
-                    self.session.params, tok, cache, pos)
+                return self.session._decode_step(params, tok, cache, pos)
 
-        def run_prefill(b, states):
+        def run_prefill(params, b, states):
             self.prefill_traces += 1
             if OBS.enabled:
                 OBS.counter("serve_traces_total",
@@ -238,7 +238,7 @@ class ContinuousBatchEngine:
                             "sweep holds this at 1 per step)",
                             site=self.site, step="bulk_prefill").inc()
             with self.session._bound(states):
-                return self.session._prefill_step(self.session.params, b)
+                return self.session._prefill_step(params, b)
 
         def splice(cache, pc, slot):
             """Write a (1, ...) prefill cache into a slot's row.  Scan
@@ -261,7 +261,7 @@ class ContinuousBatchEngine:
                     "tail": jax.tree.map(lambda z: z.at[slot].set(0),
                                          cache["tail"])}
 
-        self._decode = jax.jit(run_decode, donate_argnums=(1,))
+        self._decode = jax.jit(run_decode, donate_argnums=(2,))
         self._prefill = jax.jit(run_prefill)
         self._splice = jax.jit(splice, donate_argnums=(0,))
         self._reset = jax.jit(reset_slot, donate_argnums=(0,))
@@ -401,7 +401,8 @@ class ContinuousBatchEngine:
         jnp = self._jnp
         P = req.prompt.size
         logits, pcache = self._prefill(
-            {"tokens": jnp.asarray(req.prompt[None, :])}, self._st())
+            self.session.params, {"tokens": jnp.asarray(req.prompt[None, :])},
+            self._st())
         self._cache = self._splice(self._cache, pcache,
                                    jnp.asarray(req.slot, jnp.int32))
         tok = int(np.argmax(np.asarray(logits[0], np.float32)))
@@ -449,7 +450,8 @@ class ContinuousBatchEngine:
                           "(out of max_slots)", site=self.site,
                           slots=str(self.max_slots)).observe(len(live))
         logits, self._cache = self._decode(
-            jnp.asarray(tok), self._cache, jnp.asarray(pos), self._st())
+            self.session.params, jnp.asarray(tok), self._cache,
+            jnp.asarray(pos), self._st())
         largs = np.argmax(np.asarray(logits, np.float32), axis=-1)
 
         finished: List[Request] = []

@@ -13,12 +13,9 @@ from jax.sharding import Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh with explicit Auto axis types where the installed jax
-    supports them (jax.sharding.AxisType landed after 0.4.x)."""
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
+    """jax.make_mesh with explicit Auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -22,8 +22,9 @@ in Python-unrolled layers are keyed ``"<tag>#<ordinal>"`` (model tags
 repeat across layers; trace order is deterministic); call sites inside
 the model's ``lax.scan`` over layer periods are keyed
 ``"<group>.<period>:<tag>#<ordinal>"`` (``group`` is ``dec``/``enc``)
-and their per-period states ride the scan as stacked xs -- a leading
-layer axis on every state leaf -- so full-depth scanned models get the
+and the scan body picks its period's states by the period index it
+carries (never a stacked copy of every period) -- so full-depth
+scanned models get the
 same zero-recompile corner/age/remap sweeps as unrolled ones (the
 legacy bake-in-at-trace-time fallback is gone).  A deployment is
 serializable either way: ``--state-save`` writes the served per-site
@@ -98,7 +99,13 @@ class ServeSession:
         # prompt, sampling) gets its own derived key
         root = jax.random.PRNGKey(seed)
         k_init, k_prompt, k_img, k_enc, self._key = jax.random.split(root, 5)
-        params = S.init_train_state(k_init, cfg)["params"]
+        # parameters only (the key init_train_state gives them, so the
+        # values match a trainer's): serving needs no optimizer moments.
+        # Each leaf is drawn in f32 and rounded to bf16 as it is made, so
+        # no f32 copy of the whole model is ever held.
+        from repro.models.common import init_params
+        from repro.models.model import model_schema
+        params = init_params(k_init, model_schema(cfg), dtype=jnp.bfloat16)
         self.params = jax.tree.map(lambda v: v.astype(jnp.bfloat16), params)
         prompt = jax.random.randint(k_prompt, (batch, prompt_len), 0,
                                     cfg.vocab_size)
@@ -117,7 +124,7 @@ class ServeSession:
         self._prefill_step = S.make_prefill_step(cfg, pcfg)
         self._decode_step = S.make_decode_step(cfg, pcfg)
         # per-site state threading: unrolled sites as plain traced args,
-        # scanned sites as stacked lax.scan xs (see module docstring)
+        # scanned sites picked per period inside the scan (module docstring)
         self.threading = executor is not None
         self._sites: Optional[Dict[str, object]] = None
         self._steps_built = False
@@ -196,7 +203,9 @@ class ServeSession:
     def _build_steps(self):
         jax = self._jax
 
-        def run_prefill(b, states):
+        # params ride as arguments: closed over, they would be embedded
+        # in every executable as constants (a full-width model twice over)
+        def run_prefill(params, b, states):
             self.prefill_traces += 1           # trace-time side effect
             if OBS.enabled:
                 OBS.counter("serve_traces_total",
@@ -204,9 +213,9 @@ class ServeSession:
                             "sweep holds this at 1 per step)",
                             site=self.site, step="prefill").inc()
             with self._bound(states):
-                return self._prefill_step(self.params, b)
+                return self._prefill_step(params, b)
 
-        def run_decode(tok, cache, pos, states):
+        def run_decode(params, tok, cache, pos, states):
             self.decode_traces += 1
             if OBS.enabled:
                 OBS.counter("serve_traces_total",
@@ -214,10 +223,10 @@ class ServeSession:
                             "sweep holds this at 1 per step)",
                             site=self.site, step="decode").inc()
             with self._bound(states):
-                return self._decode_step(self.params, tok, cache, pos)
+                return self._decode_step(params, tok, cache, pos)
 
         self._prefill = jax.jit(run_prefill)
-        self._decode = jax.jit(run_decode, donate_argnums=(1,))
+        self._decode = jax.jit(run_decode, donate_argnums=(2,))
         self._steps_built = True
 
     def generate(self, states: Optional[dict] = None) -> dict:
@@ -235,13 +244,16 @@ class ServeSession:
         if not self._steps_built:
             self._build_steps()
         if states is None:
+            # release the last corner's states first: at full width one
+            # set of states is GBs, and two need not coexist
+            self._last_states = None
             states = self.states() if self.threading else {}
         self._last_states = states
         B, P, G = self.B, self.P, self.G
         total = P + G
 
         t0 = time.time()
-        logits, pcache = self._prefill(self.batch, states)
+        logits, pcache = self._prefill(self.params, self.batch, states)
         logits.block_until_ready()
         t_prefill = time.time() - t0
         if OBS.enabled:
@@ -278,7 +290,7 @@ class ServeSession:
         t0 = time.time()
         for i in range(G - 1):
             ts = time.perf_counter() if OBS.enabled else 0.0
-            logits, cache = self._decode(tok, cache,
+            logits, cache = self._decode(self.params, tok, cache,
                                          jnp.asarray(P + i, jnp.int32),
                                          states)
             if OBS.enabled:
@@ -404,6 +416,8 @@ def main():
 
     import jax
     import jax.numpy as jnp
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     # optional: serve the MLP projections on emulated analog hardware (the
     # SEMULATOR serving path; uses the cached-conductance-plan fast path)

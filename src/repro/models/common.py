@@ -231,16 +231,16 @@ def dense(x: jax.Array, w: jax.Array, tag: str = "") -> jax.Array:
 
 # --------------------------------------------------------------------------- #
 # Scan-states channel: lets the model's lax.scan over layer periods thread
-# per-period DeploymentStates as scan xs.  The provider (an analog
-# _StateBinding) exposes:
+# per-period DeploymentStates.  The provider (an analog _StateBinding)
+# exposes:
 #   recording          -- True while discovering call sites (period loop is
 #                         Python-unrolled so dense() sees concrete weights)
 #   scan_record(g, p)  -- context: record period p of scan group g
-#   scan_xs(g, n)      -- stacked per-period state pytree (leading axis n)
-#                         to feed lax.scan as xs, or None when group g has
-#                         no bound states
+#   scan_pick(g, n)    -- function of the traced period index returning
+#                         that period's state pytree, or None when group g
+#                         has no bound states
 #   scan_slice(g, ls)  -- context: serve the scan body's current period
-#                         from the traced per-period slice ls
+#                         from the traced per-period states ls
 # The model never imports the analog layer; it only calls this protocol.
 # --------------------------------------------------------------------------- #
 class _ScanStatesState(threading.local):

@@ -122,9 +122,9 @@ def _run_stack(stack_params, x, *, cfg: ArchConfig, pcfg: ParallelConfig,
     ``DeploymentState``s), the scanned periods cooperate with it: in
     record mode the period loop is Python-unrolled so every ``dense()``
     call site sees its CONCRETE per-period weight slice (call sites keyed
-    ``"{scan_group}.{period}:{tag}#{ordinal}"``); in serve mode the
-    provider's stacked per-period states ride the scan as xs, so each
-    period's sites resolve against traced state slices and the whole
+    ``"{scan_group}.{period}:{tag}#{ordinal}"``); in serve mode the scan
+    carries the period index and the provider picks that period's traced
+    states, so each period's sites resolve against them and the whole
     stack stays ONE compiled step -- scanned models get the same
     zero-recompile state swaps as unrolled ones."""
     provider = scan_states_provider()
@@ -179,34 +179,42 @@ def _run_stack(stack_params, x, *, cfg: ArchConfig, pcfg: ParallelConfig,
                 new_caches["scan"] = jax.tree.map(
                     lambda *vs: jnp.stack(vs), *ncs)
         else:
-            xs_states = (provider.scan_xs(scan_group, n)
-                         if provider is not None else None)
+            pick = (provider.scan_pick(scan_group, n)
+                    if provider is not None else None)
+            # the period index, not stacked states, rides the scan: a
+            # stacked copy of every period's states would double their
+            # device footprint for the length of the step
+            periods = jnp.arange(n) if pick is not None else None
+
+            def states_at(i):
+                return None if pick is None else pick(i)
+
             if mode == "decode":
                 def body(carry, xs):
-                    lp, lc, ls = xs
+                    lp, lc, i = xs
                     x, aux = carry
-                    x, aux, nc = period(x, aux, lp, lc, ls)
+                    x, aux, nc = period(x, aux, lp, lc, states_at(i))
                     return (x, aux), nc
                 (x, aux), ys = jax.lax.scan(
-                    body, (x, aux), (scan_params, caches["scan"], xs_states))
+                    body, (x, aux), (scan_params, caches["scan"], periods))
                 new_caches["scan"] = ys
             elif mode == "prefill":
                 def body(carry, xs):
-                    lp, ls = xs
+                    lp, i = xs
                     x, aux = carry
-                    x, aux, nc = period(x, aux, lp, None, ls)
+                    x, aux, nc = period(x, aux, lp, None, states_at(i))
                     return (x, aux), nc
                 (x, aux), ys = jax.lax.scan(body, (x, aux),
-                                            (scan_params, xs_states))
+                                            (scan_params, periods))
                 new_caches["scan"] = ys
             else:
                 def body(carry, xs):
-                    lp, ls = xs
+                    lp, i = xs
                     x, aux = carry
-                    x, aux, _ = period(x, aux, lp, None, ls)
+                    x, aux, _ = period(x, aux, lp, None, states_at(i))
                     return (x, aux), None
                 (x, aux), _ = jax.lax.scan(body, (x, aux),
-                                           (scan_params, xs_states))
+                                           (scan_params, periods))
 
     for i, kind in enumerate(tail_kinds):
         lc = None

@@ -16,20 +16,13 @@ from jax.sharding import PartitionSpec as P
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, axis_names=None):
-    """jax.shard_map across jax versions: new API (jax.shard_map, check_vma,
-    axis_names) when present, else jax.experimental.shard_map (check_rep,
-    auto = complement of the manual axes)."""
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": False}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {"check_rep": False}
+    """``jax.shard_map`` without the replication check; ``axis_names``
+    restricts the manual axes (the rest stay automatic)."""
+    kw = {"check_vma": False}
     if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def int8_compress(x: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -135,8 +135,16 @@ def test_emulator_block_grid(geom, M, NB, NO):
 # --------------------------------------------------------------------------- #
 # emulator_block_unified (ONE kernel, every device corner)
 # --------------------------------------------------------------------------- #
+# The kernel evaluates apply_blocklast's math in its own 2-d layout: the
+# zero-voltage projection is summed per window position, the conv/FC
+# stack runs as block-diagonal contractions, and CELU goes through exp
+# (Mosaic has no expm1).  So the two agree to f32 rounding, not bitwise:
+# measured |diff| <= 4e-6 on outputs of magnitude ~3.
+UNIFIED_F32_TOL = dict(rtol=1e-5, atol=1e-5)
+
+
 def _unified_fixture(geom, n_periph=2, NB=2, NO=3, M=6, seed=5):
-    """aux/pre + drive tensors for the unified serving kernel."""
+    """aux/g_norm/pre + drive tensors for the unified serving kernel."""
     from repro.core import conv4xbar
     from repro.models.common import init_params
     key = jax.random.PRNGKey(seed)
@@ -149,51 +157,54 @@ def _unified_fixture(geom, n_periph=2, NB=2, NO=3, M=6, seed=5):
     u = jax.random.uniform(jax.random.fold_in(key, 2), (M, NB, D, H))
     pos = (jax.random.uniform(jax.random.fold_in(key, 3),
                               (M, NB, D, H)) > 0.5).astype(jnp.float32)
-    return aux, pre, u, pos
+    return aux, g, pre, u, pos
 
 
 @pytest.mark.parametrize("geom", [CASE_A, CASE_B], ids=lambda g: g.name)
-@pytest.mark.parametrize("block_m", [4, 8])  # 6 % 4 != 0: pad-and-slice
+@pytest.mark.parametrize("block_m", [4, 8])  # blocks per step, rounded to 8
 def test_emulator_block_unified_ideal_bitwise(geom, block_m):
-    """Ideal corner: the fused kernel (interpret mode) is BIT-IDENTICAL to
-    the chunked XLA fast path -- same dual_rail_stage1/_tail_stages code,
-    different schedule."""
+    """Ideal corner: the fused kernel (interpret mode) matches the chunked
+    XLA fast path within UNIFIED_F32_TOL at any block tiling (the
+    output-group axis, NO=3, is padded to a whole tile and sliced back)."""
     from repro.core import conv4xbar
     from repro.kernels.emulator_block.emulator_block import (
         emulator_block_unified_pallas)
-    aux, pre, u, pos = _unified_fixture(geom)
+    aux, g, pre, u, pos = _unified_fixture(geom)
     ref = conv4xbar.apply_blocklast(aux, pre, u, pos, chunk=3)
-    out = emulator_block_unified_pallas(aux, pre, u, pos, block_m=block_m,
+    out = emulator_block_unified_pallas(aux, g, u, pos, block_n=block_m,
                                         interpret=True)
     assert out.shape == ref.shape
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               **UNIFIED_F32_TOL)
 
 
 def test_emulator_block_unified_conditioned():
-    """Conditioned corner: the scenario epilogue (fc0 shift) matches the
-    XLA path, and the all-zero feature encoding reproduces the ideal
-    corner of the same net exactly -- one compiled kernel per shape serves
-    every corner."""
+    """Conditioned corner: the scenario epilogue (fc0 shift, flat and
+    per-tile) matches the XLA path, and the all-zero feature encoding
+    reproduces the ideal corner of the same net exactly -- one compiled
+    kernel per shape serves every corner."""
     from repro.core import conv4xbar
     from repro.kernels.emulator_block.emulator_block import (
         emulator_block_unified_pallas)
     from repro.nonideal import N_SCENARIO_FEATURES
-    aux, pre, u, pos = _unified_fixture(
+    aux, g, pre, u, pos = _unified_fixture(
         CASE_A, n_periph=2 + N_SCENARIO_FEATURES)
     sfeat = jnp.linspace(-0.5, 0.5, N_SCENARIO_FEATURES)
-    shift = sfeat @ aux["f0_scen"]
-    ref = conv4xbar.apply_blocklast(aux, pre, u, pos, chunk=2,
-                                    fc0_shift=shift)
-    out = emulator_block_unified_pallas(aux, pre, u, pos, shift=shift,
-                                        block_m=4, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-6, atol=1e-7)
+    tiled = jax.random.uniform(jax.random.PRNGKey(1),
+                               (2 * 3, N_SCENARIO_FEATURES))
+    for shift in (sfeat @ aux["f0_scen"], tiled @ aux["f0_scen"]):
+        ref = conv4xbar.apply_blocklast(aux, pre, u, pos, chunk=2,
+                                        fc0_shift=shift)
+        out = emulator_block_unified_pallas(aux, g, u, pos, shift=shift,
+                                            block_n=4, interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   **UNIFIED_F32_TOL)
     # zero features == no epilogue == the plain ideal evaluation, bitwise
     z = jnp.zeros((N_SCENARIO_FEATURES,)) @ aux["f0_scen"]
-    out_z = emulator_block_unified_pallas(aux, pre, u, pos, shift=z,
-                                          block_m=4, interpret=True)
-    out_n = emulator_block_unified_pallas(aux, pre, u, pos, shift=None,
-                                          block_m=4, interpret=True)
+    out_z = emulator_block_unified_pallas(aux, g, u, pos, shift=z,
+                                          block_n=4, interpret=True)
+    out_n = emulator_block_unified_pallas(aux, g, u, pos, shift=None,
+                                          block_n=4, interpret=True)
     np.testing.assert_array_equal(np.asarray(out_z), np.asarray(out_n))
 
 
@@ -228,9 +239,9 @@ def test_emulator_block_unified_bf16():
     from repro.core import conv4xbar
     from repro.kernels.emulator_block.emulator_block import (
         emulator_block_unified_pallas)
-    aux, pre, u, pos = _unified_fixture(CASE_A)
+    aux, g, pre, u, pos = _unified_fixture(CASE_A)
     ref = conv4xbar.apply_blocklast(aux, pre, u, pos, chunk=2)
-    out = emulator_block_unified_pallas(aux, pre, u, pos, block_m=8,
+    out = emulator_block_unified_pallas(aux, g, u, pos, block_n=8,
                                         interpret=True,
                                         compute_dtype=jnp.bfloat16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -238,15 +249,20 @@ def test_emulator_block_unified_bf16():
 
 
 def test_emulator_block_unified_dispatcher_fallback_bitwise():
-    """The dispatcher's two routes (pallas kernel / chunked XLA) are
-    bit-identical in f32, so ``use_pallas`` is a pure scheduling choice."""
+    """The dispatcher's two routes off the TPU (interpreted kernel /
+    chunked XLA) agree within UNIFIED_F32_TOL, and the XLA route is
+    bit-identical whether it is handed the precompute or derives it."""
     from repro.kernels.emulator_block import emulator_block_unified
-    aux, pre, u, pos = _unified_fixture(CASE_A)
-    y_xla = emulator_block_unified(aux, pre, u, pos, use_pallas=False,
+    aux, g, pre, u, pos = _unified_fixture(CASE_A)
+    y_xla = emulator_block_unified(aux, g, u, pos, use_pallas=False,
                                    chunk=2)
-    y_pl = emulator_block_unified(aux, pre, u, pos, use_pallas=True,
-                                  interpret=True, block_m=4)
-    np.testing.assert_array_equal(np.asarray(y_pl), np.asarray(y_xla))
+    y_pre = emulator_block_unified(aux, g, u, pos, pre=pre,
+                                   use_pallas=False, chunk=2)
+    y_pl = emulator_block_unified(aux, g, u, pos, use_pallas=True,
+                                  block_n=4)
+    np.testing.assert_array_equal(np.asarray(y_pre), np.asarray(y_xla))
+    np.testing.assert_allclose(np.asarray(y_pl), np.asarray(y_xla),
+                               **UNIFIED_F32_TOL)
 
 
 def test_unified_kernel_compile_once_across_corners():
@@ -298,8 +314,9 @@ def test_emulator_block_pad_batch():
 
 
 def test_autotune_cache_and_report(tmp_path, monkeypatch):
-    """best_config: sweep once, then memory hit, then (fresh process
-    simulated by clearing memory) disk hit; report records the source."""
+    """best_config: a candidate that fails raises; a sweep runs once,
+    then memory hit, then (fresh process simulated by clearing memory)
+    disk hit; report records the source."""
     from repro.kernels import autotune
     monkeypatch.setenv("REPRO_AUTOTUNE", "1")
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "at.json"))
@@ -312,8 +329,11 @@ def test_autotune_cache_and_report(tmp_path, monkeypatch):
             raise ValueError("does not compile")  # losing candidate
 
     cands = [{"b": b} for b in (4, 8)]
+    with pytest.raises(ValueError, match="does not compile"):
+        autotune.best_config("k", (1, 2), cands, measure, {"b": 16})
+    cands = cands[:1]
     cfg = autotune.best_config("k", (1, 2), cands, measure, {"b": 16})
-    assert cfg["b"] == 4 and 8 in calls
+    assert cfg["b"] == 4
     assert autotune.report()["k"]["source"] == "swept"
     calls.clear()
     assert autotune.best_config("k", (1, 2), cands, measure, {"b": 16}) == cfg

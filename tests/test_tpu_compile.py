@@ -1,0 +1,102 @@
+"""Compile-only checks of the unified emulator kernel for a TPU v5e.
+
+The TPU compiler is installed with jaxlib, and it compiles for a chip
+that is described, not attached: these tests run on the CPU and catch
+what interpret mode cannot (Mosaic's block-tiling rule, gathers it does
+not lower, scoped-VMEM overflow) at the real widths of gemma3-1b's MLP
+projections.  The topology is described inside a fixture, never at
+import time: only one process may load the TPU library, and every test
+worker imports this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.configs.rram_ps32 import CASE_A
+from repro.core import conv4xbar
+from repro.kernels.emulator_block.emulator_block import (
+    emulator_block_unified_pallas)
+from repro.kernels.emulator_block.ops import BLOCK_N_CANDIDATES
+from repro.models.common import init_params
+
+V5E_HBM_BYTES = 16 * 1024 ** 3
+M_PREFILL = 32                     # batch 2 x prompt 16 rows per matmul
+
+
+def _mlp_shapes():
+    c = get_config("gemma3-1b")
+    return {"gate_up": (c.d_model, c.d_ff), "down": (c.d_ff, c.d_model)}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    from jax.experimental import topologies
+    # the compiler would otherwise write its logs outside the checkout
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                               # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(one_chip, site, block_n, compute_dtype):
+    K, N = _mlp_shapes()[site]
+    g = CASE_A
+    blk_in = g.tiles * g.rows
+    NB, NO = -(-K // blk_in), N // g.outputs
+    schema = conv4xbar.conv4xbar_schema(g, n_periph=2)
+    eparams = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0),
+                                                 schema))
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fwd(ep, gn, u, pos):
+        aux = conv4xbar.blocklast_weights(ep, g)
+        return emulator_block_unified_pallas(
+            aux, gn, u, pos, block_n=block_n, interpret=False,
+            compute_dtype=compute_dtype)
+
+    args = (jax.tree.map(lambda a: sds(a.shape, a.dtype), eparams),
+            sds((NB, NO, g.tiles, g.rows, g.cols)),
+            sds((M_PREFILL, NB, g.tiles, g.rows)),
+            sds((M_PREFILL, NB, g.tiles, g.rows)))
+    return jax.jit(fwd).lower(*args).compile()
+
+
+def _check(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert used < V5E_HBM_BYTES, used
+
+
+@pytest.mark.parametrize("site", ["gate_up", "down"])
+@pytest.mark.parametrize("block_n", BLOCK_N_CANDIDATES)
+def test_unified_kernel_compiles_f32(one_chip, site, block_n):
+    """Every block size the autotuner may pick compiles for the v5e at
+    the served widths, and the kernel is a Mosaic custom call."""
+    _check(_compile(one_chip, site, block_n, jnp.float32))
+
+
+def test_unified_kernel_compiles_bf16(one_chip):
+    """The bf16-operand variant compiles at the gate/up width."""
+    _check(_compile(one_chip, "gate_up", BLOCK_N_CANDIDATES[0],
+                    jnp.bfloat16))

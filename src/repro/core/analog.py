@@ -57,7 +57,6 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import time
 import warnings
 import zlib
 from typing import Callable, Dict, Optional, Tuple
@@ -489,7 +488,8 @@ class AnalogExecutor:
                         "materialized device-state cache lookups",
                         tag=tag or "<anon>", event="miss").inc()
         sc = dep.scenario
-        with jax.ensure_compile_time_eval():
+        with OBS.span("analog_state_build", tag=tag or "<anon>"), \
+                jax.ensure_compile_time_eval():
             ep = (self.emulator_params
                   if self.acfg.backend == "emulator"
                   and self.emulator_params is not None else {})
@@ -615,7 +615,8 @@ class AnalogExecutor:
         # force eager evaluation even under an enclosing jit trace: the plan
         # must come out concrete so it is computed once and cached, not
         # re-tiled inside the compiled graph on every call
-        with jax.ensure_compile_time_eval():
+        with OBS.span("analog_plan_build", tag=tag or "<anon>"), \
+                jax.ensure_compile_time_eval():
             plan = build_conductance_plan(w, self.acfg, self.geom)
             if self.mesh is not None:
                 # sharded like the states made from it: at full width the
@@ -1139,7 +1140,8 @@ class AnalogExecutor:
                 OBS.counter("analog_traces_total",
                             "jit traces of the per-tag unified forward",
                             tag=tag).inc()
-            return _st_matmul_u(self, tag, x2, w, st)
+            with jax.named_scope("analog:" + tag):
+                return _st_matmul_u(self, tag, x2, w, st)
 
         fn = jax.jit(_fwd)
         self._fns[tag] = (w, rls, fn)
@@ -1159,35 +1161,29 @@ class AnalogExecutor:
         through its compiled serving steps this way); by default the state
         derives from ``deploy(...)``'s spec, and the ideal deployment is
         bit-identical to the plain serving fast path."""
-        lead = x.shape[:-1]
-        x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
-        t0 = time.perf_counter() if OBS.enabled else 0.0
-        if _is_tracer(x2) or _is_tracer(w) or not tag:
-            mode = "eager"
-            if state is None:
-                a, b = self.calibration.get(tag, (1.0, 0.0))
-                state = self._inline_state(tag, w, a, b)
-            y = _st_matmul_u(self, tag, x2, w, state)
-        else:
-            mode = "jit"
-            st = state if state is not None else self.state_for(tag, w)
-            y = self._unified_for(tag, w)(x2, st)
-        if OBS.enabled:
-            # dispatch latency, NOT synchronized compute time: no
-            # block_until_ready is added here (that would serialize the
-            # dispatch pipeline the serving loop depends on).  "jit" is
-            # the per-tag compiled forward; "eager" is the in-trace /
-            # anonymous-tag path (under an enclosing jit this records
-            # once, at trace time).
-            dt = time.perf_counter() - t0
-            OBS.histogram("analog_matmul_seconds",
-                          "unified-forward dispatch latency, split "
-                          "eager-vs-jit (host-side, no device sync)",
-                          mode=mode).observe(dt)
-            OBS.counter("analog_matmul_calls_total",
-                        "analog matmul calls per tag and dispatch mode",
-                        tag=tag or "<anon>", mode=mode).inc()
-        return y.reshape(*lead, w.shape[1]).astype(x.dtype)
+        # every device op of the site, the kernel included, carries
+        # "analog:<site>" in its op_name (the device trace's per-site view)
+        with jax.named_scope("analog:" + tag):
+            lead = x.shape[:-1]
+            x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+            if _is_tracer(x2) or _is_tracer(w) or not tag:
+                mode = "eager"
+                if state is None:
+                    a, b = self.calibration.get(tag, (1.0, 0.0))
+                    state = self._inline_state(tag, w, a, b)
+                y = _st_matmul_u(self, tag, x2, w, state)
+            else:
+                mode = "jit"
+                st = state if state is not None else self.state_for(tag, w)
+                y = self._unified_for(tag, w)(x2, st)
+            if OBS.enabled:
+                # "jit" is the per-tag compiled forward; "eager" is the
+                # in-trace / anonymous-tag path (under an enclosing jit it
+                # counts once, at trace time)
+                OBS.counter("analog_matmul_calls_total",
+                            "analog matmul calls per tag and dispatch mode",
+                            tag=tag or "<anon>", mode=mode).inc()
+            return y.reshape(*lead, w.shape[1]).astype(x.dtype)
 
     # ------------------------------------------------------------------ #
     @contextlib.contextmanager

@@ -208,7 +208,8 @@ class ContinuousBatchEngine:
         self.decode_traces = 0
         self._states: Optional[dict] = None
         self._cache = None
-        self._build()
+        with OBS.span("engine_build"):
+            self._build()
 
     # ------------------------------------------------------------------ #
     # Compiled calls (shape-stable in max_slots)
@@ -285,13 +286,14 @@ class ContinuousBatchEngine:
         ``load_deployment``) are placed onto the executor's serving mesh
         first, so a mid-run hot-swap keeps the compiled tick's input
         shardings stable (docs/parallel.md)."""
-        if states is not None:
-            if self.session.threading:
-                states = self.session.ex.shard_states(states)
-            self._states = states
-        else:
-            self._states = (self.session.states()
-                            if self.session.threading else {})
+        with OBS.span("engine_refresh_states"):
+            if states is not None:
+                if self.session.threading:
+                    states = self.session.ex.shard_states(states)
+                self._states = states
+            else:
+                self._states = (self.session.states()
+                                if self.session.threading else {})
 
     def _st(self) -> dict:
         if self._states is None:
@@ -400,12 +402,13 @@ class ContinuousBatchEngine:
     def _bulk_prefill(self, req: Request) -> None:
         jnp = self._jnp
         P = req.prompt.size
-        logits, pcache = self._prefill(
-            self.session.params, {"tokens": jnp.asarray(req.prompt[None, :])},
-            self._st())
-        self._cache = self._splice(self._cache, pcache,
-                                   jnp.asarray(req.slot, jnp.int32))
-        tok = int(np.argmax(np.asarray(logits[0], np.float32)))
+        with OBS.span("engine_prefill"):
+            logits, pcache = self._prefill(
+                self.session.params,
+                {"tokens": jnp.asarray(req.prompt[None, :])}, self._st())
+            self._cache = self._splice(self._cache, pcache,
+                                       jnp.asarray(req.slot, jnp.int32))
+            tok = int(np.argmax(np.asarray(logits[0], np.float32)))
         req.out.append(tok)
         req.next_pos = P
         req.t_first = time.monotonic()
@@ -426,54 +429,71 @@ class ContinuousBatchEngine:
 
     def step(self) -> List[Request]:
         """One scheduler tick: admit, then one batched decode over all
-        live slots.  Returns the requests that finished this tick."""
+        live slots.  Returns the requests that finished this tick.
+
+        With telemetry on, the tick is the span ``engine_tick`` and its
+        phases are child spans: ``engine_admit``, ``engine_pack`` (the
+        token and position arrays), ``engine_dispatch`` (the decode
+        call until it returns), ``engine_fetch`` (the logits to the
+        host, which waits for the device) and ``engine_update``
+        (sampling and bookkeeping)."""
+        with OBS.span("engine_tick"):
+            return self._tick()
+
+    def _tick(self) -> List[Request]:
         jnp = self._jnp
-        self.try_admit()
+        with OBS.span("engine_admit"):
+            self.try_admit()
         live = [(i, self.requests[rid]) for i, rid in enumerate(self.slots)
                 if rid is not None]
         if not live:
             return []
-        tok = np.zeros((self.max_slots, 1), np.int32)
-        pos = np.zeros((self.max_slots,), np.int32)
-        for i, req in live:
-            if req.status == PREFILL:
-                tok[i, 0] = req.prompt[req.next_pos]
-            else:
-                tok[i, 0] = req.out[-1]
-            pos[i] = req.next_pos
-        if OBS.enabled:
-            OBS.gauge("serve_slots_active",
-                      "live request slots this tick", site=self.site) \
-                .set(len(live))
-            OBS.histogram("serve_batch_occupancy",
-                          "live slots per batched decode tick "
-                          "(out of max_slots)", site=self.site,
-                          slots=str(self.max_slots)).observe(len(live))
-        logits, self._cache = self._decode(
-            self.session.params, jnp.asarray(tok), self._cache,
-            jnp.asarray(pos), self._st())
-        largs = np.argmax(np.asarray(logits, np.float32), axis=-1)
-
-        finished: List[Request] = []
-        n_dec = 0
-        for i, req in live:
-            req.next_pos += 1
-            if req.status == PREFILL:
-                if req.next_pos >= req.prompt.size:   # prompt consumed:
-                    req.out.append(int(largs[i]))     # first generated tok
-                    req.t_first = time.monotonic()
-                    req.status = RUNNING
+        with OBS.span("engine_pack"):
+            tok = np.zeros((self.max_slots, 1), np.int32)
+            pos = np.zeros((self.max_slots,), np.int32)
+            for i, req in live:
+                if req.status == PREFILL:
+                    tok[i, 0] = req.prompt[req.next_pos]
+                else:
+                    tok[i, 0] = req.out[-1]
+                pos[i] = req.next_pos
+            if OBS.enabled:
+                OBS.gauge("serve_slots_active",
+                          "live request slots this tick", site=self.site) \
+                    .set(len(live))
+                OBS.histogram("serve_batch_occupancy",
+                              "live slots per batched decode tick "
+                              "(out of max_slots)", site=self.site,
+                              slots=str(self.max_slots)).observe(len(live))
+        with OBS.span("engine_dispatch"):
+            logits, self._cache = self._decode(
+                self.session.params, jnp.asarray(tok), self._cache,
+                jnp.asarray(pos), self._st())
+        with OBS.span("engine_fetch"):
+            host = np.asarray(logits, np.float32)
+        with OBS.span("engine_update"):
+            largs = np.argmax(host, axis=-1)
+            finished: List[Request] = []
+            n_dec = 0
+            for i, req in live:
+                req.next_pos += 1
+                if req.status == PREFILL:
+                    if req.next_pos >= req.prompt.size:   # prompt consumed:
+                        req.out.append(int(largs[i]))     # first generated
+                        req.t_first = time.monotonic()
+                        req.status = RUNNING
+                        n_dec += 1
+                else:
+                    req.out.append(int(largs[i]))
                     n_dec += 1
-            else:
-                req.out.append(int(largs[i]))
-                n_dec += 1
-            if req.status == RUNNING and len(req.out) >= req.max_new:
-                self._finish(req)
-                finished.append(req)
-        if OBS.enabled and n_dec:
-            OBS.counter("serve_engine_tokens_total",
-                        "tokens through the engine (prompt + generated)",
-                        site=self.site, kind="decode").inc(n_dec)
+                if req.status == RUNNING and len(req.out) >= req.max_new:
+                    self._finish(req)
+                    finished.append(req)
+            if OBS.enabled and n_dec:
+                OBS.counter("serve_engine_tokens_total",
+                            "tokens through the engine (prompt + "
+                            "generated)", site=self.site,
+                            kind="decode").inc(n_dec)
         return finished
 
     @property

@@ -105,8 +105,11 @@ class ServeSession:
         # no f32 copy of the whole model is ever held.
         from repro.models.common import init_params
         from repro.models.model import model_schema
-        params = init_params(k_init, model_schema(cfg), dtype=jnp.bfloat16)
-        self.params = jax.tree.map(lambda v: v.astype(jnp.bfloat16), params)
+        with OBS.span("session_init"):
+            params = init_params(k_init, model_schema(cfg),
+                                 dtype=jnp.bfloat16)
+            self.params = jax.tree.map(lambda v: v.astype(jnp.bfloat16),
+                                       params)
         prompt = jax.random.randint(k_prompt, (batch, prompt_len), 0,
                                     cfg.vocab_size)
         self.batch = {"tokens": prompt}
@@ -146,8 +149,8 @@ class ServeSession:
             from repro.models.common import use_dense_hook, use_scan_states
             rec: Dict[str, object] = {}
             binding = _StateBinding(record=rec)
-            with use_dense_hook(self.ex.hook), use_scan_states(binding), \
-                    self.ex.bound_states(binding):
+            with OBS.span("session_sites"), use_dense_hook(self.ex.hook), \
+                    use_scan_states(binding), self.ex.bound_states(binding):
                 self._jax.eval_shape(
                     lambda b: self._prefill_step(self.params, b), self.batch)
             self._sites = rec
@@ -156,8 +159,9 @@ class ServeSession:
     def states(self) -> Dict[str, object]:
         """One ready-to-serve ``DeploymentState`` per call site,
         materialized from the executor's ACTIVE deployment."""
-        sts = {sk: self.ex.state_for(sk, w)
-               for sk, w in self.sites().items()}
+        sites = self.sites()
+        with OBS.span("session_states"):
+            sts = {sk: self.ex.state_for(sk, w) for sk, w in sites.items()}
         if OBS.enabled:
             for sk in sts:
                 OBS.counter("serve_state_swaps_total",
@@ -289,21 +293,9 @@ class ServeSession:
         out_tokens, out_logits = [tok], [logits]
         t0 = time.time()
         for i in range(G - 1):
-            ts = time.perf_counter() if OBS.enabled else 0.0
             logits, cache = self._decode(self.params, tok, cache,
                                          jnp.asarray(P + i, jnp.int32),
                                          states)
-            if OBS.enabled:
-                # per-step DISPATCH latency: deliberately no
-                # block_until_ready inside the loop (a host sync per
-                # step would serialize the dispatch pipeline -- see the
-                # comment above); the synchronized total lands in
-                # serve_decode_seconds below
-                OBS.histogram("serve_decode_step_seconds",
-                              "per-step decode dispatch latency (host "
-                              "side, no device sync)", site=self.site,
-                              arch=self.cfg.name).observe(
-                                  time.perf_counter() - ts)
             if self.temperature > 0:
                 self._key, sub = jax.random.split(self._key)
                 tok = jax.random.categorical(

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.conv4xbar import ConvStage, conv_out_sizes
+from repro.obs import OBS
 
 
 def _stage_apply(h, w, b, st: ConvStage):
@@ -240,9 +241,9 @@ _NT = (((1,), (1,)), ((), ()))             # a @ b.T
 
 def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
                     n_tail: int, n_fc: int, compute_dtype):
-    """Grid step (block tile j, batch row m): BOTH rails of the dual-rail
+    """Grid step (block tile j, row tile i): BOTH rails of the dual-rail
     delta factorization and the whole conv/FC stack for ``bn`` crossbar
-    blocks, in VMEM.
+    blocks and ``bm`` batch rows, in VMEM.
 
     Layout: blocks ride the sublane dim, and each (window position kk,
     tile d, bitline w) piece of a block keeps its row groups and channels
@@ -251,7 +252,10 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
     the wrapper, and every slice is a static index of a ref's leading
     dim -- the forms Mosaic lowers.  The stage-0 precompute (``g0``, its
     zero-voltage response and stage-1 projection) is evaluated here from
-    the normalized conductances rather than read from HBM."""
+    the normalized conductances rather than read from HBM, once per step
+    and shared by the ``bm`` rows: from the drive on, the rows are
+    stacked in the sublanes, ``(m, block)``, so each stage is one
+    contraction over ``bm * bn`` rows."""
     (u_ref, pos_ref, gn_ref, sh_ref, ev_ref, eg_ref, b0_ref, w1_ref, b1_ref,
      em_ref) = refs[:10]
     idx = 10
@@ -266,7 +270,8 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
         fcs.append((refs[idx], refs[idx + 1]))
         idx += 2
     o_ref = refs[idx]
-    n_out = o_ref.shape[2] // 2
+    n_out = o_ref.shape[3] // 2
+    bm, bn = u_ref.shape[3], gn_ref.shape[1]
 
     if compute_dtype == jnp.float32:
         gemm = _exact_dot
@@ -276,17 +281,27 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
                 a.astype(compute_dtype), b.astype(compute_dtype), dims,
                 preferred_element_type=jnp.float32)
 
+    def by_block(a):
+        """(bn, L), one row per block -> (bm*bn, L), the same for each m."""
+        return jnp.broadcast_to(a[None], (bm,) + a.shape).reshape(
+            bm * bn, a.shape[1])
+
+    def by_row(a):
+        """(bm, L), one row per batch row -> (bm*bn, L), for each block."""
+        return jnp.broadcast_to(a[:, None], (bm, bn, a.shape[1])).reshape(
+            bm * bn, a.shape[1])
+
     ev, eg, em = ev_ref[...], eg_ref[...], em_ref[...]
     b0, b1 = b0_ref[...], b1_ref[...]
     w1 = [w1_ref[kk] for kk in range(k1)]
     wo_n = W // kw
 
     def tile(d, accs):
-        # the wordline drive (one batch row) and the positive-rail mask,
+        # the wordline drive of the bm rows and their positive-rail mask,
         # expanded from row groups g to the (g, c) lanes of each piece
-        rows = [pl.ds(kk * D + d, 1) for kk in range(k1)]
-        v0 = [_exact_dot(u_ref[0, 0, r, :], ev) for r in rows]
-        mk = [_exact_dot(pos_ref[0, 0, r, :], em) for r in rows]
+        v0 = [_exact_dot(u_ref[0, 0, kk * D + d], ev) for kk in range(k1)]
+        mk = [by_row(_exact_dot(pos_ref[0, 0, kk * D + d], em))
+              for kk in range(k1)]
         h = [[], []]                       # rail -> [bitline w]
         for w in range(W):
             y0, t_full, t_pos = b1, None, None
@@ -295,10 +310,12 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
                 g0 = _exact_dot(gn_ref[d, :, lanes], eg) + b0
                 c0 = _celu(g0)
                 y0 = y0 + _exact_dot(c0, w1[kk])
-                t = gemm(_celu(v0[kk] + g0) - c0, w1[kk])
+                t = gemm(_celu(by_row(v0[kk]) + by_block(g0))
+                         - by_block(c0), w1[kk])
                 t_full = t if t_full is None else t_full + t
                 tp = t * mk[kk]
                 t_pos = tp if t_pos is None else t_pos + tp
+            y0 = by_block(y0)
             for r, pre in enumerate((y0 + t_pos, y0 + t_full - t_pos)):
                 x = _celu(pre)
                 for wk_ref, bk_ref in tail:
@@ -316,18 +333,23 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
             out.append(acc)
         return tuple(out)
 
-    acc0 = jnp.broadcast_to(f0b_ref[...] + sh_ref[...],
-                            (gn_ref.shape[1], f0b_ref.shape[1]))
+    acc0 = by_block(jnp.broadcast_to(f0b_ref[...] + sh_ref[...],
+                                     (bn, f0b_ref.shape[1])))
     accs = jax.lax.fori_loop(0, D, tile, (acc0, acc0))
     for r in range(2):
         x = accs[r]
         for fw_ref, fb_ref in fcs[:-1]:
             x = gemm(_celu(x), fw_ref[...]) + fb_ref[...]
-        # the last layer is contracted transposed, (n_out, bn): blocks
-        # land in the lanes, so the output is not padded to 128 lanes
+        # the last layer is contracted transposed, a row at a time,
+        # (n_out, bn): blocks land in the lanes, so the output is not
+        # padded to 128 lanes, and with the rows leading the output block
+        # the wrapper's first relayout of it is a bitcast
         fw_ref, fb_ref = fcs[-1]
-        y = gemm(fw_ref[...], _celu(x), _NT) + fb_ref[...]
-        o_ref[0, 0, r * n_out:(r + 1) * n_out, :] = y.astype(o_ref.dtype)
+        x = _celu(x)
+        for m in range(bm):
+            y = gemm(fw_ref[...], x[m * bn:(m + 1) * bn], _NT) + fb_ref[...]
+            o_ref[0, 0, m, r * n_out:(r + 1) * n_out, :] = y.astype(
+                o_ref.dtype)
 
 
 def _const_spec(arr):
@@ -337,6 +359,20 @@ def _const_spec(arr):
 def _kron_eye(n: int, w: jax.Array) -> jax.Array:
     """Block-diagonal ``kron(I_n, w)``: one copy of ``w`` per row group."""
     return jnp.kron(jnp.eye(n, dtype=jnp.float32), w.astype(jnp.float32))
+
+
+# stacked rows (batch rows x crossbar blocks) one grid step of the unified
+# kernel holds: at the served widths its working set then fits the v5e's
+# default scoped VMEM (tests/test_tpu_compile.py)
+STEP_ROWS = 512
+
+
+def row_tile(M: int, bn: int) -> tuple[int, int]:
+    """(bm, row tiles) for M batch rows at ``bn`` blocks a step: all M
+    rows in one tile when ``M * bn`` fits ``STEP_ROWS``, else the fewest
+    tiles that fit, of equal size, so the last pads M the least."""
+    mt = -(-M // max(1, STEP_ROWS // bn))
+    return -(-M // mt), mt
 
 
 def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
@@ -355,7 +391,10 @@ def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
     block-indexed for per-tile feature operands -- None = ideal, an exact
     zero add.  ``block_n`` crossbar blocks share one grid step (rounded
     to a multiple of 8; the output-group axis is zero-padded to a whole
-    number of tiles and sliced back).
+    number of tiles and sliced back), and so do ``bm`` batch rows
+    (``row_tile``: all M rows where they fit, the batch axis padded to
+    whole row tiles otherwise), which share the step's conductance-only
+    work.
 
     Numerics: the same math as ``conv4xbar.apply_blocklast``, associated
     differently -- the stage-1 projection of the zero-voltage response
@@ -383,6 +422,13 @@ def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
     bn = -(-min(block_n, NO) // 8) * 8
     NOp = -(-NO // bn) * bn
     nbt = NOp // bn                                   # block tiles per nb
+    bm, mt = row_tile(M, bn)
+    Mp = bm * mt
+    if OBS.enabled:
+        OBS.gauge("emulator_kernel_rows_per_pass",
+                  "batch rows that share one conductance pass of the "
+                  "unified emulator kernel (rows per grid step)",
+                  m=str(M), nb=str(NB), no=str(NO)).set(bm)
 
     # tiles d lead, blocks next, (kk, w, g) in the lanes.  The wrapper's
     # ops sit under two named scopes, so the device trace tells the
@@ -397,9 +443,12 @@ def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
                                                     k1 * W * G)
 
     with jax.named_scope("emu_layout_io"):
-        def drive(a):                                     # -> (M, NB, k1*D, G)
-            a = a.astype(jnp.float32).reshape(M, NB, D, G, k1)
-            return a.transpose(0, 1, 4, 2, 3).reshape(M, NB, k1 * D, G)
+        def drive(a):                           # -> (mt, NB, k1*D, bm, G)
+            a = a.astype(jnp.float32)
+            if Mp != M:
+                a = jnp.pad(a, ((0, Mp - M),) + ((0, 0),) * 3)
+            a = a.reshape(mt, bm, NB, D, G, k1).transpose(0, 2, 5, 3, 1, 4)
+            return a.reshape(mt, NB, k1 * D, bm, G)
 
         tiled = shift is not None and shift.ndim == 2
         if shift is None:
@@ -433,12 +482,12 @@ def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
         consts += [f32(fcs[-1][0]).T, f32(fcs[-1][1])[:, None]]
         du, dp, sh = drive(u01), drive(pos01), f32(shift)
 
-    blk = lambda j, m: (m, j // nbt, 0, 0)
+    blk = lambda j, i: (i, j // nbt, 0, 0, 0)
     in_specs = [
-        pl.BlockSpec((1, 1, k1 * D, G), blk),
-        pl.BlockSpec((1, 1, k1 * D, G), blk),
-        pl.BlockSpec((D, bn, k1 * W * G), lambda j, m: (0, j, 0)),
-        (pl.BlockSpec((bn, F0), lambda j, m: (j, 0))
+        pl.BlockSpec((1, 1, k1 * D, bm, G), blk),
+        pl.BlockSpec((1, 1, k1 * D, bm, G), blk),
+        pl.BlockSpec((D, bn, k1 * W * G), lambda j, i: (0, j, 0)),
+        (pl.BlockSpec((bn, F0), lambda j, i: (j, 0))
          if tiled else _const_spec(shift)),
     ] + [_const_spec(c) for c in consts]
 
@@ -446,17 +495,17 @@ def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
         functools.partial(_unified_kernel, D=D, W=W, G=G, k1=k1, kw=kw,
                           n_tail=len(aux["hstages"]) - 1, n_fc=n_fc,
                           compute_dtype=compute_dtype),
-        grid=(NB * nbt, M),
+        grid=(NB * nbt, mt),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 2 * n_out, bn),
-                               lambda j, m: (m, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, NB * nbt, 2 * n_out, bn),
+        out_specs=pl.BlockSpec((1, 1, bm, 2 * n_out, bn),
+                               lambda j, i: (i, j, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((mt, NB * nbt, bm, 2 * n_out, bn),
                                        jnp.float32),
         interpret=interpret,
         name="emulator_block_unified",
     )(du, dp, gn, sh, *consts)
     with jax.named_scope("emu_layout_io"):
-        out = out.reshape(M, NB, nbt, 2, n_out, bn).transpose(3, 0, 1, 2,
-                                                              5, 4)
-        out = out.reshape(2, M, NB, NOp, n_out)[:, :, :, :NO]
+        out = out.reshape(mt, NB, nbt, bm, 2, n_out, bn)
+        out = out.transpose(4, 0, 3, 1, 2, 6, 5)
+        out = out.reshape(2, Mp, NB, NOp, n_out)[:, :M, :, :NO]
         return out.reshape(2, M * NB * NO, n_out)

@@ -7,6 +7,7 @@ import pytest
 
 from repro.configs.base import AnalogConfig
 from repro.configs.rram_ps32 import CASE_A, CASE_B
+from repro.kernels.emulator_block.emulator_block import STEP_ROWS
 
 
 # --------------------------------------------------------------------------- #
@@ -176,6 +177,68 @@ def test_emulator_block_unified_ideal_bitwise(geom, block_m):
     assert out.shape == ref.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                **UNIFIED_F32_TOL)
+
+
+@pytest.mark.parametrize("geom", [CASE_A, CASE_B], ids=lambda g: g.name)
+@pytest.mark.parametrize("block_n", [4, 8])     # rounded to 8 blocks a step
+# one row, one row tile, and (STEP_ROWS // 8 + 3) several row tiles with
+# the last one padded
+@pytest.mark.parametrize("M", [1, 4, 6, STEP_ROWS // 8 + 3])
+def test_emulator_block_unified_row_tiles(geom, block_n, M):
+    """Rows that share a grid step, and so its conductance-only pass, read
+    as they would alone: under a block-indexed scenario shift the kernel
+    matches the XLA path, and each row matches an M = 1 launch of that
+    row's drive, within UNIFIED_F32_TOL.  NO = 10 makes two block tiles
+    per block group."""
+    from repro.core import conv4xbar
+    from repro.kernels.emulator_block.emulator_block import (
+        emulator_block_unified_pallas)
+    from repro.nonideal import N_SCENARIO_FEATURES
+    NB, NO = 2, 10
+    aux, g, pre, u, pos = _unified_fixture(
+        geom, n_periph=2 + N_SCENARIO_FEATURES, NB=NB, NO=NO, M=M)
+    feats = jax.random.uniform(jax.random.PRNGKey(4),
+                               (NB * NO, N_SCENARIO_FEATURES))
+    shift = feats @ aux["f0_scen"]
+    launch = lambda uu, pp: emulator_block_unified_pallas(
+        aux, g, uu, pp, shift=shift, block_n=block_n, interpret=True)
+    out = launch(u, pos)
+    ref = conv4xbar.apply_blocklast(aux, pre, u, pos, chunk=2,
+                                    fc0_shift=shift)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               **UNIFIED_F32_TOL)
+    alone = jax.vmap(lambda uu, pp: launch(uu[None], pp[None]))(u, pos)
+    rows = out.reshape(2, M, NB * NO, -1).transpose(1, 0, 2, 3)
+    np.testing.assert_allclose(np.asarray(rows), np.asarray(alone),
+                               **UNIFIED_F32_TOL)
+
+
+def test_emulator_kernel_rows_per_pass_gauge():
+    """With telemetry on, a launch records how many batch rows share one
+    conductance pass (set while tracing, so the output is unchanged); with
+    it off, nothing is recorded."""
+    from repro.kernels.emulator_block.emulator_block import (
+        emulator_block_unified_pallas)
+    from repro.obs import OBS
+    aux, g, pre, u, pos = _unified_fixture(CASE_A, M=4)
+    launch = lambda: np.asarray(emulator_block_unified_pallas(
+        aux, g, u, pos, block_n=8, interpret=True))
+    assert not OBS.enabled                        # suite default
+    OBS.reset()
+    off = launch()
+    assert "emulator_kernel_rows_per_pass" not in OBS.snapshot()["metrics"]
+    OBS.enable()
+    try:
+        on = launch()
+        met = OBS.snapshot()["metrics"]["emulator_kernel_rows_per_pass"]
+    finally:
+        OBS.reset()
+        OBS.disable()
+    (s,) = met["series"]
+    assert met["kind"] == "gauge"
+    assert s["labels"] == {"m": "4", "nb": "2", "no": "3"}
+    assert s["value"] == 4
+    np.testing.assert_array_equal(on, off)
 
 
 def test_emulator_block_unified_conditioned():

@@ -3,10 +3,10 @@
 The TPU compiler is installed with jaxlib, and it compiles for a chip
 that is described, not attached: these tests run on the CPU and catch
 what interpret mode cannot (Mosaic's block-tiling rule, gathers it does
-not lower, scoped-VMEM overflow) at the real widths of gemma3-1b's MLP
-projections.  The topology is described inside a fixture, never at
-import time: only one process may load the TPU library, and every test
-worker imports this file.
+not lower, scoped-VMEM overflow) at the real widths of the MLP
+projections of gemma3-1b and DeepSeek-Coder-33B.  The topology is
+described inside a fixture, never at import time: only one process may
+load the TPU library, and every test worker imports this file.
 """
 import jax
 import jax.numpy as jnp
@@ -25,8 +25,8 @@ V5E_HBM_BYTES = 16 * 1024 ** 3
 M_PREFILL = 32                     # batch 2 x prompt 16 rows per matmul
 
 
-def _mlp_shapes():
-    c = get_config("gemma3-1b")
+def _mlp_shapes(arch="gemma3-1b"):
+    c = get_config(arch)
     return {"gate_up": (c.d_model, c.d_ff), "down": (c.d_ff, c.d_model)}
 
 
@@ -55,8 +55,9 @@ def one_chip(topo):
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-def _compile(one_chip, site, block_n, compute_dtype):
-    K, N = _mlp_shapes()[site]
+def _compile(one_chip, site, block_n, compute_dtype, arch="gemma3-1b",
+             M=M_PREFILL):
+    K, N = _mlp_shapes(arch)[site]
     g = CASE_A
     blk_in = g.tiles * g.rows
     NB, NO = -(-K // blk_in), N // g.outputs
@@ -75,13 +76,20 @@ def _compile(one_chip, site, block_n, compute_dtype):
 
     args = (jax.tree.map(lambda a: sds(a.shape, a.dtype), eparams),
             sds((NB, NO, g.tiles, g.rows, g.cols)),
-            sds((M_PREFILL, NB, g.tiles, g.rows)),
-            sds((M_PREFILL, NB, g.tiles, g.rows)))
+            sds((M, NB, g.tiles, g.rows)),
+            sds((M, NB, g.tiles, g.rows)))
     return jax.jit(fwd).lower(*args).compile()
 
 
 def _check(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's output reaches the wrapper's relayouts through bitcasts
+    # alone, so the one device op a launch that names the kernel is the
+    # kernel itself (a consumer's HLO text names its operands)
+    for line in text.splitlines():
+        if "%emulator_block_unified" in line and "custom-call(" not in line:
+            assert " bitcast(" in line, line
     ma = compiled.memory_analysis()
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
@@ -100,3 +108,15 @@ def test_unified_kernel_compiles_bf16(one_chip):
     """The bf16-operand variant compiles at the gate/up width."""
     _check(_compile(one_chip, "gate_up", BLOCK_N_CANDIDATES[0],
                     jnp.bfloat16))
+
+
+@pytest.mark.parametrize("site", ["gate_up", "down"])
+@pytest.mark.parametrize("M", [4, 8])
+@pytest.mark.parametrize("block_n", BLOCK_N_CANDIDATES)
+def test_unified_kernel_compiles_dsc33b_f32(one_chip, site, M, block_n):
+    """At DeepSeek-Coder-33B's MLP widths (7168 -> 19200 -> 7168) and the
+    decode and prefill row counts of its benchmark cell, the row tile
+    (``STEP_ROWS`` stacked rows a grid step) fits the default scoped
+    VMEM."""
+    _check(_compile(one_chip, site, block_n, jnp.float32,
+                    arch="deepseek-coder-33b", M=M))

@@ -28,10 +28,13 @@ class MoEConfig:
     num_experts: int = 16
     top_k: int = 2
     capacity_factor: float = 1.25
-    eval_capacity_factor: float = 2.0
     shared_expert: bool = False        # llama4-style always-on shared expert
     router_aux_coef: float = 0.01
     router_jitter: float = 0.0
+    # "softmax": softmax over the experts, top-k, renormalized;
+    # "sparsemixer": Phi-3.5-MoE's masked-softmax argmax per choice, its
+    # mask threshold 2 * router_jitter, multipliers not renormalized
+    router: str = "softmax"
 
 
 @dataclass(frozen=True)
@@ -157,6 +160,8 @@ class ArchConfig:
     rope_base_global: float = 0.0      # 0 -> same as rope_base
     qk_norm: bool = False
     qkv_bias: bool = False
+    o_bias: bool = False               # attention output projection bias
+    head_bias: bool = False            # LM head bias
     mlp_gated: bool = True
     mlp_act: str = "silu"              # silu | gelu | relu
     norm: str = "rmsnorm"              # rmsnorm | layernorm

@@ -69,13 +69,16 @@ class ServeSession:
             out = sess.generate()          # zero recompiles across sigmas
 
     With ``executor=None`` the session serves the plain digital model
-    (the reference for task-level accuracy).
+    (the reference for task-level accuracy).  ``params`` serves the given
+    weights (the serving layout of ``models.model.model_schema``, bf16)
+    in place of the session's own draw, which is then not made.
     """
 
     def __init__(self, arch: str, *, reduced: bool = True,
                  reduced_layers: Optional[int] = None, batch: int = 4,
                  prompt_len: int = 32, gen: int = 16,
-                 temperature: float = 0.0, seed: int = 0, executor=None):
+                 temperature: float = 0.0, seed: int = 0, executor=None,
+                 params=None):
         import jax
         import jax.numpy as jnp
         from repro.configs import get_config, reduced as reduce_cfg
@@ -103,13 +106,15 @@ class ServeSession:
         # values match a trainer's): serving needs no optimizer moments.
         # Each leaf is drawn in f32 and rounded to bf16 as it is made, so
         # no f32 copy of the whole model is ever held.
-        from repro.models.common import init_params
-        from repro.models.model import model_schema
-        with OBS.span("session_init"):
-            params = init_params(k_init, model_schema(cfg),
-                                 dtype=jnp.bfloat16)
-            self.params = jax.tree.map(lambda v: v.astype(jnp.bfloat16),
-                                       params)
+        if params is None:
+            from repro.models.common import init_params
+            from repro.models.model import model_schema
+            with OBS.span("session_init"):
+                params = init_params(k_init, model_schema(cfg),
+                                     dtype=jnp.bfloat16)
+                params = jax.tree.map(lambda v: v.astype(jnp.bfloat16),
+                                      params)
+        self.params = params
         prompt = jax.random.randint(k_prompt, (batch, prompt_len), 0,
                                     cfg.vocab_size)
         self.batch = {"tokens": prompt}
@@ -209,7 +214,7 @@ class ServeSession:
 
         # params ride as arguments: closed over, they would be embedded
         # in every executable as constants (a full-width model twice over)
-        def run_prefill(params, b, states):
+        def run_prefill(params, b, states, all_positions=False, taps=False):
             self.prefill_traces += 1           # trace-time side effect
             if OBS.enabled:
                 OBS.counter("serve_traces_total",
@@ -217,7 +222,7 @@ class ServeSession:
                             "sweep holds this at 1 per step)",
                             site=self.site, step="prefill").inc()
             with self._bound(states):
-                return self._prefill_step(params, b)
+                return self._prefill_step(params, b, all_positions, taps)
 
         def run_decode(params, tok, cache, pos, states):
             self.decode_traces += 1
@@ -229,9 +234,32 @@ class ServeSession:
             with self._bound(states):
                 return self._decode_step(params, tok, cache, pos)
 
-        self._prefill = jax.jit(run_prefill)
+        self._prefill = jax.jit(run_prefill,
+                                static_argnames=("all_positions", "taps"))
         self._decode = jax.jit(run_decode, donate_argnums=(2,))
         self._steps_built = True
+
+    def prefill(self, tokens=None, states: Optional[dict] = None, *,
+                all_positions: bool = False, taps: bool = False) -> dict:
+        """One prefill of a (B, S) token batch (default: the session's
+        prompt): the step ``generate`` starts with.
+
+        ``states`` as in ``generate``; ``all_positions`` and ``taps`` as in
+        ``models.model.prefill`` (each pair of them is its own compiled
+        step).  Returns device arrays, unsynced: ``{"logits", "cache"}``,
+        and ``"taps"`` when asked."""
+        if not self._steps_built:
+            self._build_steps()
+        if states is None:
+            self._last_states = None
+            states = self.states() if self.threading else {}
+        self._last_states = states
+        b = self.batch if tokens is None else {"tokens": tokens}
+        # only what differs from generate's call, so the two share a trace
+        kw = {k: True for k, on in (("all_positions", all_positions),
+                                    ("taps", taps)) if on}
+        out = self._prefill(self.params, b, states, **kw)
+        return dict(zip(("logits", "cache", "taps"), out))
 
     def generate(self, states: Optional[dict] = None) -> dict:
         """One prefill + greedy/temperature decode pass.

@@ -33,9 +33,18 @@ from repro.configs.base import (ArchConfig, ParallelConfig, GLOBAL_ATTN,
                                 LOCAL_ATTN, CHUNKED_ATTN, BIDIR_ATTN)
 from repro.models.common import (ParamSchema, apply_norm, apply_rope,
                                  axis_size, current_mesh, dense, dense_schema,
-                                 dp_axes, norm_schema, shard)
+                                 dp_axes, norm_schema, shard, tap)
 
 NEG_INF = -1e30
+
+
+def _scores(spec, q, k):
+    """Attention scores in f32, produced once: a bf16 score read by the
+    running max and by the exponent may be rounded in one fusion and not
+    in the other (XLA's excess precision), and with scores spread over
+    6e4 -- an untrained crossbar emulator's q and k reach |x| ~ 100 --
+    exp(s - max) then overflows (non-finite logits on a TPU v5e)."""
+    return jnp.einsum(spec, q, k, preferred_element_type=jnp.float32)
 
 
 # --------------------------------------------------------------------------- #
@@ -54,6 +63,8 @@ def attention_schema(cfg: ArchConfig, *, cross: bool = False):
         s["bq"] = ParamSchema((qf,), P("model"), "zeros")
         s["bk"] = ParamSchema((kvf,), P(None), "zeros")
         s["bv"] = ParamSchema((kvf,), P(None), "zeros")
+    if cfg.o_bias and not cross:
+        s["bo"] = ParamSchema((d,), P(None), "zeros")
     if cfg.qk_norm:
         s["qnorm"] = norm_schema(cfg.head_dim, "rmsnorm")
         s["knorm"] = norm_schema(cfg.head_dim, "rmsnorm")
@@ -121,7 +132,10 @@ def _out_proj(params, o, cfg: ArchConfig):
         # once, so the out-projection contracts an unsharded dim (XLA would
         # otherwise emit a fp32 all-reduce of the residual stream).
         o = shard(o, "dp", None, None)
-    return dense(o, params["wo"], "attn.o")
+    out = dense(o, params["wo"], "attn.o")
+    if "bo" in params:
+        out = out + params["bo"].astype(out.dtype)
+    return out
 
 
 def _mixer_gather(x, pcfg, mode):
@@ -150,7 +164,6 @@ def flash_attention(q, k, v, *, causal: bool, q_offset=0, k_offset=0,
     kv_len = Sk
     Sk = k.shape[1]
     nb = Sk // bk
-    q = q * (D ** -0.5)
     q_pos = q_offset + jnp.arange(Sq)
 
     def c_spec(*tail):  # carry spec for (B, H, Sq, *tail)
@@ -171,7 +184,9 @@ def flash_attention(q, k, v, *, causal: bool, q_offset=0, k_offset=0,
     def body(carry, xs):
         kb, vb, start = xs
         m, l, o = carry
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kb).astype(jnp.float32)
+        # scaled in f32: the bf16 operands are the rotated q and k as the
+        # layer holds them (no second rounding of q)
+        s = _scores("bqhd,bkhd->bhqk", q, kb) * (D ** -0.5)
         k_pos = start + jnp.arange(bk)
         if causal:
             mask = k_pos[None, :] <= q_pos[:, None]
@@ -239,7 +254,7 @@ def _grouped_windowed(q, k, v, w: int, *, sliding: bool):
         q5 = shard(q5, *spec)
         k5 = shard(k5, *spec)
         v5 = shard(v5, *spec)
-        s = jnp.einsum("bnhqd,bnhkd->bnhqk", q5 * (D ** -0.5), k5).astype(jnp.float32)
+        s = _scores("bnhqd,bnhkd->bnhqk", q5 * (D ** -0.5), k5)
         s = jnp.where(mask_n[None, :, None], s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         o5 = jnp.einsum("bnhqk,bnhkd->bnhqd", p.astype(v5.dtype), v5)
@@ -250,14 +265,14 @@ def _grouped_windowed(q, k, v, w: int, *, sliding: bool):
         kg = shard(k5.reshape(B, G, wk, D), *gspec)
         vg = shard(v5.reshape(B, G, wk, D), *gspec)
         mask_g = jnp.repeat(mask_n, H, axis=0)        # (G,w,wk) n-major like G
-        s = jnp.einsum("bgqd,bgkd->bgqk", qg * (D ** -0.5), kg).astype(jnp.float32)
+        s = _scores("bgqd,bgkd->bgqk", qg * (D ** -0.5), kg)
         s = jnp.where(mask_g[None], s, NEG_INF)
         s = shard(s, *gspec)
         p = jax.nn.softmax(s, axis=-1)
         og = jnp.einsum("bgqk,bgkd->bgqd", p.astype(vg.dtype), vg)
         o5 = shard(og, *gspec).reshape(B, n, H, w, D)
     else:
-        s = jnp.einsum("bnhqd,bnhkd->bnhqk", q5 * (D ** -0.5), k5).astype(jnp.float32)
+        s = _scores("bnhqd,bnhkd->bnhqk", q5 * (D ** -0.5), k5)
         s = jnp.where(mask_n[None, :, None], s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         o5 = jnp.einsum("bnhqk,bnhkd->bnhqd", p.astype(v5.dtype), v5)
@@ -311,7 +326,7 @@ def decode_attention(q, ck, cv, valid_mask, cfg: ArchConfig):
     D = q.shape[-1]
     g = cfg.num_heads // cfg.num_kv_heads
     qg = q.reshape(B, 1, cfg.num_kv_heads, g, D)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg * (D ** -0.5), ck).astype(jnp.float32)
+    s = _scores("bqhgd,bkhd->bhgqk", qg * (D ** -0.5), ck)
     if valid_mask.ndim == 1:
         valid_mask = valid_mask[None]
     s = jnp.where(valid_mask[:, None, None, None, :], s, NEG_INF)
@@ -342,7 +357,7 @@ def sharded_flash_decode(q, ck, cv, pos, cfg: ArchConfig, *, tp_axis="model"):
     def f(q, ck, cv, pos):
         off = jax.lax.axis_index(tp_axis) * S_local
         qg = q.reshape(q.shape[0], 1, cfg.num_kv_heads, g, D)
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg * (D ** -0.5), ck).astype(jnp.float32)
+        s = _scores("bqhgd,bkhd->bhgqk", qg * (D ** -0.5), ck)
         valid = (jnp.arange(S_local) + off) <= pos
         s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
         m = s.max(axis=-1)
@@ -464,6 +479,7 @@ def attn_mixer(params, x, *, cfg: ArchConfig, pcfg: ParallelConfig, kind: str,
     elif head_tp:
         q = shard(q, "dp", None, "model", None)
     q = apply_rope(q, positions, base)
+    tap("attn.q_rot", q)
     k, v = _project_kv(params, x, cfg)
     if not windowed or head_tp:
         if head_tp and cfg.num_kv_heads % axis_size("model") == 0:

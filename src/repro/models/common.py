@@ -221,12 +221,52 @@ def use_dense_hook(fn):
 
 
 def dense(x: jax.Array, w: jax.Array, tag: str = "") -> jax.Array:
-    """y = x @ w over the last dim of x; interceptable by the analog backend."""
-    if _HOOK.fn is not None:
-        out = _HOOK.fn(x, w, tag)
-        if out is not None:
-            return out
-    return jnp.einsum("...k,kf->...f", x, w.astype(x.dtype))
+    """y = x @ w over the last dim of x; interceptable by the analog backend.
+    Tapped (``tap``) as ``<tag>:in`` and ``<tag>``."""
+    out = _HOOK.fn(x, w, tag) if _HOOK.fn is not None else None
+    if out is None:
+        out = jnp.einsum("...k,kf->...f", x, w.astype(x.dtype))
+    if tag:
+        tap(tag + ":in", x)
+        tap(tag, out)
+    return out
+
+
+# --------------------------------------------------------------------------- #
+# Taps: named intermediate values that a check of the served model reads
+# (each crossbar site's drive and output, a layer's input, the routes).
+# ``tap`` is a no-op unless a ``tapping`` collector is open, so a program
+# traced without one carries none of them.  A scanned layer's taps leave
+# the scan beside its cache (``models.model._run_stack``).
+# --------------------------------------------------------------------------- #
+class _TapState(threading.local):
+    def __init__(self):
+        self.taps = None
+
+
+_TAP = _TapState()
+
+
+def tap(name: str, value) -> None:
+    """Keep ``value`` under ``name`` in the open collector, if any."""
+    if _TAP.taps is not None:
+        _TAP.taps[name] = value
+
+
+def tapping_on() -> bool:
+    return _TAP.taps is not None
+
+
+@contextlib.contextmanager
+def tapping(on: bool = True):
+    """A fresh collector for the ``tap``s made inside (yielded), or None
+    and nothing collected when not ``on``."""
+    prev = _TAP.taps
+    _TAP.taps = {} if on else prev
+    try:
+        yield _TAP.taps if on else None
+    finally:
+        _TAP.taps = prev
 
 
 # --------------------------------------------------------------------------- #

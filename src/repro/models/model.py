@@ -21,7 +21,7 @@ from repro.models.blocks import (apply_layer, layer_schema, layer_cache_schema)
 from repro.models.common import (ParamSchema, abstract_array, apply_norm,
                                  current_mesh, dense, norm_schema,
                                  scan_states_provider, shard, stack_schema,
-                                 _sanitize_spec)
+                                 tap, tapping, tapping_on, _sanitize_spec)
 
 NEG_INF = -1e30
 
@@ -38,6 +38,8 @@ def model_schema(cfg: ArchConfig) -> Dict[str, Any]:
     }
     if not cfg.tie_embeddings:
         s["head"] = ParamSchema((d, vp), P("data", "model"), "normal", d ** -0.5)
+    if cfg.head_bias:
+        s["head_bias"] = ParamSchema((vp,), P("model"), "zeros")
     if cfg.frontend == "vision":
         s["proj"] = ParamSchema((d, d), P("data", "model"), "normal", d ** -0.5)
 
@@ -112,6 +114,19 @@ def _remat_wrap(fn, pcfg: ParallelConfig):
     return jax.checkpoint(fn)
 
 
+def _period_slice(v, p: int):
+    """Period ``p`` of a stacked parameter while call sites are recorded.
+    The params are concrete (closed over): a period's 2-D weights -- the
+    only kind dense() takes -- are sliced OUT of the ambient trace, so
+    dense() records real arrays, not tracers that would leak out of the
+    eval_shape scope; larger banks (a MoE layer's experts) are sliced in
+    the trace, so no concrete copy of them is made."""
+    if v.ndim <= 3:
+        with jax.ensure_compile_time_eval():
+            return v[p]
+    return v[p]
+
+
 def _run_stack(stack_params, x, *, cfg: ArchConfig, pcfg: ParallelConfig,
                pattern, tail_kinds, mode, caches, pos, positions, enc_out,
                scan_group: str = "dec"):
@@ -141,10 +156,15 @@ def _run_stack(stack_params, x, *, cfg: ArchConfig, pcfg: ParallelConfig,
                 x = shard(x, "dp", None, None)
             ncs = {}
             for i, kind in enumerate(pattern):
-                x, nc, a = apply_layer(
-                    lp[f"p{i}"], x, cfg=cfg, pcfg=pcfg, kind=kind, mode=mode,
-                    cache=None if lc is None else lc.get(f"p{i}"),
-                    pos=pos, positions=positions, enc_out=enc_out)
+                with tapping(tapping_on()) as taps:
+                    tap("x_in", x)
+                    x, nc, a = apply_layer(
+                        lp[f"p{i}"], x, cfg=cfg, pcfg=pcfg, kind=kind,
+                        mode=mode,
+                        cache=None if lc is None else lc.get(f"p{i}"),
+                        pos=pos, positions=positions, enc_out=enc_out)
+                if taps is not None:
+                    nc = dict(nc or {}, taps=taps)
                 if nc is not None:
                     ncs[f"p{i}"] = nc
                 aux = aux + a
@@ -165,11 +185,7 @@ def _run_stack(stack_params, x, *, cfg: ArchConfig, pcfg: ParallelConfig,
             # closed-over params and their slices are concrete)
             ncs = []
             for p in range(n):
-                # the params are concrete (closed over); slice them OUT of
-                # the ambient trace so dense() records real arrays, not
-                # tracers that would leak out of the eval_shape scope
-                with jax.ensure_compile_time_eval():
-                    lp = jax.tree.map(lambda v: v[p], scan_params)
+                lp = jax.tree.map(lambda v: _period_slice(v, p), scan_params)
                 lc = (jax.tree.map(lambda v: v[p], caches["scan"])
                       if mode == "decode" else None)
                 with provider.scan_record(scan_group, p):
@@ -220,9 +236,14 @@ def _run_stack(stack_params, x, *, cfg: ArchConfig, pcfg: ParallelConfig,
         lc = None
         if mode == "decode":
             lc = caches["tail"].get(f"t{i}")
-        x, nc, a = apply_layer(
-            stack_params["tail"][f"t{i}"], x, cfg=cfg, pcfg=pcfg, kind=kind,
-            mode=mode, cache=lc, pos=pos, positions=positions, enc_out=enc_out)
+        with tapping(tapping_on()) as taps:
+            tap("x_in", x)
+            x, nc, a = apply_layer(
+                stack_params["tail"][f"t{i}"], x, cfg=cfg, pcfg=pcfg,
+                kind=kind, mode=mode, cache=lc, pos=pos, positions=positions,
+                enc_out=enc_out)
+        if taps is not None:
+            nc = dict(nc or {}, taps=taps)
         aux = aux + a
         if nc is not None:
             new_caches["tail"][f"t{i}"] = nc
@@ -250,10 +271,34 @@ def encode(params, enc_frames, *, cfg: ArchConfig, pcfg: ParallelConfig):
     return apply_norm(params["encoder"]["final_norm"], x, cfg.norm), aux
 
 
+def _split_taps(caches, cfg: ArchConfig):
+    """(a prefill's caches without the layers' taps, each layer's taps in
+    depth order)."""
+    n_pat = len(cfg.pattern)
+    found = []                                   # (layer, taps)
+    out = {"scan": {}, "tail": {}}
+    for key, c in caches["scan"].items():
+        c = dict(c)
+        t = c.pop("taps")
+        if c:
+            out["scan"][key] = c
+        i = int(key[1:])
+        for p in range(jax.tree.leaves(t)[0].shape[0]):
+            found.append((p * n_pat + i, jax.tree.map(lambda a, p=p: a[p], t)))
+    for key, c in caches["tail"].items():
+        c = dict(c)
+        found.append((cfg.num_periods * n_pat + int(key[1:]), c.pop("taps")))
+        if c:
+            out["tail"][key] = c
+    found.sort(key=lambda lt: lt[0])
+    return out, [t for _, t in found]
+
+
 def forward(params, tokens, *, cfg: ArchConfig, pcfg: ParallelConfig,
             mode: str = "train", cache=None, pos=None, image_embeds=None,
             enc_frames=None, compute_dtype=jnp.bfloat16):
-    """Returns (hidden (B,S,D), new_cache_or_None, aux_loss)."""
+    """Returns (hidden (B,S,D), new_cache_or_None, aux_loss).  Tapped:
+    ``final``, the last layer's output before the final norm."""
     aux = jnp.zeros((), jnp.float32)
     enc_out = None
     if cfg.encoder_layers:
@@ -283,6 +328,7 @@ def forward(params, tokens, *, cfg: ArchConfig, pcfg: ParallelConfig,
         tail_kinds=cfg.tail_kinds, mode=mode, caches=cache, pos=pos,
         positions=positions, enc_out=enc_out)
     aux = aux + aux_d
+    tap("final", x)
     x = apply_norm(params["final_norm"], x, cfg.norm)
     return x, (new_caches if mode in ("prefill", "decode") else None), aux
 
@@ -297,6 +343,8 @@ def compute_logits(params, h, cfg: ArchConfig):
                             params["embed"].astype(h.dtype))
     else:
         logits = dense(h, params["head"], "lm_head")
+    if "head_bias" in params:
+        logits = logits + params["head_bias"].astype(logits.dtype)
     logits = logits.astype(jnp.float32)
     if cfg.logit_softcap:
         c = cfg.logit_softcap
@@ -360,13 +408,28 @@ def lm_loss(params, batch, *, cfg: ArchConfig, pcfg: ParallelConfig,
 # Serving entry points
 # --------------------------------------------------------------------------- #
 def prefill(params, tokens, *, cfg: ArchConfig, pcfg: ParallelConfig,
-            image_embeds=None, enc_frames=None, compute_dtype=jnp.bfloat16):
-    """Returns (last-position logits (B,Vp), cache)."""
-    h, cache, _ = forward(params, tokens, cfg=cfg, pcfg=pcfg, mode="prefill",
-                          image_embeds=image_embeds, enc_frames=enc_frames,
-                          compute_dtype=compute_dtype)
-    logits = compute_logits(params, h[:, -1:], cfg)[:, 0]
-    return logits, cache
+            image_embeds=None, enc_frames=None, compute_dtype=jnp.bfloat16,
+            all_positions: bool = False, taps: bool = False):
+    """Returns (logits, cache): the last position's logits (B,Vp) fp32, or
+    with ``all_positions`` every position's (B,S,Vp), as an evaluation
+    harness scores sequences.  With ``taps``, also what a check of each
+    step on its served inputs reads (``models.common.tap``): ``{"layers":
+    per layer in depth order its ``x_in``, each crossbar site's drive
+    ``<tag>:in`` and output ``<tag>``, ``attn.q_rot`` and a MoE's
+    ``moe.*``; ``final``; ``lm_head:in`` and ``lm_head``}``."""
+    with tapping(taps) as top:
+        h, cache, _ = forward(params, tokens, cfg=cfg, pcfg=pcfg,
+                              mode="prefill", image_embeds=image_embeds,
+                              enc_frames=enc_frames,
+                              compute_dtype=compute_dtype)
+        logits = compute_logits(params, h if all_positions else h[:, -1:],
+                                cfg)
+    if not all_positions:
+        logits = logits[:, 0]
+    if not taps:
+        return logits, cache
+    cache, layers = _split_taps(cache, cfg)
+    return logits, cache, dict(top, layers=layers)
 
 
 def decode_step(params, token, cache, pos, *, cfg: ArchConfig,

@@ -138,11 +138,12 @@ def make_train_step(cfg: ArchConfig, pcfg: ParallelConfig, tcfg: TrainConfig):
 def make_prefill_step(cfg: ArchConfig, pcfg: ParallelConfig):
     cdt = compute_dtype_of(pcfg)
 
-    def prefill_step(params, batch):
+    def prefill_step(params, batch, all_positions=False, taps=False):
         return M.prefill(params, batch["tokens"], cfg=cfg, pcfg=pcfg,
                          image_embeds=batch.get("image_embeds"),
                          enc_frames=batch.get("enc_frames"),
-                         compute_dtype=cdt)
+                         compute_dtype=cdt, all_positions=all_positions,
+                         taps=taps)
 
     return prefill_step
 

@@ -200,3 +200,40 @@ def test_profile_report_reads_scopes_spans_and_gaps(obs_enabled, tmp_path):
     rep = pr.report(p, win, 0.01)
     assert rep["sites"]["mlp.up#0"]["emu_layout_g"] == pytest.approx(g_s)
     assert rep["spans"]["engine_fetch"]["count"] == 1
+
+
+def test_moe_scopes_reach_the_hlo_and_the_report(obs_enabled, tmp_path):
+    """The MoE layer's router and expert ops carry ``moe_route`` and
+    ``moe_experts`` in their ``op_name``; the report sums a scope's
+    device time when asked (``--scope``)."""
+    import dataclasses
+
+    import profile_report as pr
+    from repro.configs import get_config, reduced
+    from repro.configs.base import ParallelConfig
+    from repro.models import moe
+    from repro.models.common import init_params
+    cfg = reduced(get_config("phi3.5-moe-42b-a6.6b"))
+    cfg = dataclasses.replace(cfg, d_ff=256)
+    p = init_params(jax.random.PRNGKey(0), moe.moe_schema(cfg))
+    pc = ParallelConfig()
+    fn = jax.jit(lambda x: moe.moe_mixer(p, x, cfg=cfg, pcfg=pc,
+                                         train=False)[0])
+    x = jnp.ones((2, 64, cfg.d_model), jnp.float32)
+    text = fn.lower(x).compile().as_text()
+    names = [ln.split('op_name="', 1)[1].split('"', 1)[0]
+             for ln in text.splitlines() if 'op_name="' in ln]
+    for scope in ("moe_route", "moe_experts"):
+        assert any(scope in n.split("/") for n in names), scope
+    fn(x).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("bench.call"):
+        fn(x).block_until_ready()
+    jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    prof = pr.Profile(path)
+    win = prof.window("bench.call")
+    rep = pr.report(prof, win, scopes=("moe_experts", "moe_route"))
+    assert rep["scopes"]["moe_experts"]["ops"] > 0
+    assert 0 < rep["scopes"]["moe_experts"]["s"] <= rep["device_ops_s"]

@@ -57,7 +57,8 @@ def one_chip(topo):
 
 def _compile(one_chip, site, block_n, compute_dtype, arch="gemma3-1b",
              M=M_PREFILL):
-    K, N = _mlp_shapes(arch)[site]
+    """``site``: an MLP site of ``arch`` by name, or its (K, N)."""
+    K, N = site if isinstance(site, tuple) else _mlp_shapes(arch)[site]
     g = CASE_A
     blk_in = g.tiles * g.rows
     NB, NO = -(-K // blk_in), N // g.outputs
@@ -120,3 +121,12 @@ def test_unified_kernel_compiles_dsc33b_f32(one_chip, site, M, block_n):
     VMEM."""
     _check(_compile(one_chip, site, block_n, jnp.float32,
                     arch="deepseek-coder-33b", M=M))
+
+
+@pytest.mark.parametrize("site", [(4096, 4096), (4096, 1024)],
+                         ids=["q_o", "k_v"])
+def test_unified_kernel_compiles_phi35moe_attn(one_chip, site):
+    """At Phi-3.5-MoE's attention widths and the 32 rows of its prefill
+    cell (eight row tiles of 4), the kernel fits and its output reaches
+    the wrapper through bitcasts, one kernel op a launch."""
+    _check(_compile(one_chip, site, 128, jnp.float32, M=32))

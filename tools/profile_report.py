@@ -17,10 +17,12 @@ two and reports, over a window:
   * each idle gap of the device longer than ``--gap-ms``, named by the
     innermost program span over its middle (spans recorded with
     ``OBS.enable(profiler=True)`` share the device ops' clock);
-  * each program span's count and median.
+  * each program span's count and median;
+  * the device time under each named scope given with ``--scope`` (for
+    example ``moe_route`` and ``moe_experts``, ``models/moe.py``).
 
   PYTHONPATH=src python tools/profile_report.py TRACE.xplane.pb
-      [--window SPAN] [--gap-ms 1] [--json]
+      [--window SPAN] [--gap-ms 1] [--scope NAME ...] [--json]
 
 The window is the extent of the spans named ``--window`` (default:
 every device op's extent).  An op is attributed by its own
@@ -220,7 +222,8 @@ def _part(op: str) -> str:
     return next((c for c in comps if c in LAYOUTS), "other")
 
 
-def report(p: Profile, window, gap_s: float = 1e-3) -> dict:
+def report(p: Profile, window, gap_s: float = 1e-3,
+           scopes: Tuple[str, ...] = ()) -> dict:
     ops = p._in(window)
     busy = sum(e - s for _, s, e, _ in ops) * 1e-9
     sites: Dict[str, Dict[str, float]] = {}
@@ -248,6 +251,8 @@ def report(p: Profile, window, gap_s: float = 1e-3) -> dict:
                           key=lambda r: -r[1])[:10],
         "idle_gaps": [list(g[1:]) for g in p.idle_gaps(window, gap_s)],
         "spans": span_ms,
+        "scopes": {name: dict(zip(("s", "ops"), p.scope_time(name, window)))
+                   for name in scopes},
     }
 
 
@@ -258,10 +263,13 @@ def main(argv=None):
                     help="span whose extent is the window")
     ap.add_argument("--gap-ms", type=float, default=1.0)
     ap.add_argument("--device", type=int, default=0)
+    ap.add_argument("--scope", action="append", default=[],
+                    help="a named scope whose device time to report")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
     p = Profile(args.path, args.device)
-    rep = report(p, p.window(args.window), args.gap_ms * 1e-3)
+    rep = report(p, p.window(args.window), args.gap_ms * 1e-3,
+                 tuple(args.scope))
     if args.json:
         print(json.dumps(rep, indent=1))
         return
@@ -281,6 +289,10 @@ def main(argv=None):
     print("spans (count, median ms):")
     for name, d in rep["spans"].items():
         print(f"  {name:<32} {d['count']:>6} {d['median_ms']:.4f}")
+    for name, d in rep["scopes"].items():
+        print(f"scope {name}: {d['s']:.6f} s over {d['ops']} ops "
+              f"({100 * d['s'] / max(rep['device_ops_s'], 1e-12):.3f}% of "
+              "device time)")
 
 
 if __name__ == "__main__":

@@ -240,7 +240,7 @@ _NT = (((1,), (1,)), ((), ()))             # a @ b.T
 
 
 def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
-                    n_tail: int, n_fc: int, compute_dtype):
+                    n_chunk: int, n_tail: int, n_fc: int, compute_dtype):
     """Grid step (block tile j, row tile i): BOTH rails of the dual-rail
     delta factorization and the whole conv/FC stack for ``bn`` crossbar
     blocks and ``bm`` batch rows, in VMEM.
@@ -250,12 +250,16 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
     flattened in the lanes, ``(g, c)``.  Every stage is then a 2-d
     contraction against a block-diagonal ``kron(I, w)`` weight built by
     the wrapper, and every slice is a static index of a ref's leading
-    dim -- the forms Mosaic lowers.  The stage-0 precompute (``g0``, its
-    zero-voltage response and stage-1 projection) is evaluated here from
-    the normalized conductances rather than read from HBM, once per step
-    and shared by the ``bm`` rows: from the drive on, the rows are
-    stacked in the sublanes, ``(m, block)``, so each stage is one
-    contraction over ``bm * bn`` rows."""
+    dim -- the forms Mosaic lowers.  Stage 1 contracts ``n_chunk`` lane
+    chunks of row groups one at a time against the weight of one chunk
+    (``stage1_chunk``), so no MXU pass multiplies a 128x128 tile of the
+    full ``kron(I_G, w)`` that lies off its diagonal and is all zero.
+    The stage-0 precompute (``g0``, its zero-voltage response and
+    stage-1 projection) is evaluated here from the normalized
+    conductances rather than read from HBM, once per step and shared by
+    the ``bm`` rows: from the drive on, the rows are stacked in the
+    sublanes, ``(m, block)``, so each stage is one contraction over
+    ``bm * bn`` rows."""
     (u_ref, pos_ref, gn_ref, sh_ref, ev_ref, eg_ref, b0_ref, w1_ref, b1_ref,
      em_ref) = refs[:10]
     idx = 10
@@ -296,6 +300,25 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
     w1 = [w1_ref[kk] for kk in range(k1)]
     wo_n = W // kw
 
+    cin = w1[0].shape[0]
+
+    def stage1(v, g0, w):
+        """Stage 1 of one piece, a lane chunk of row groups at a time:
+        its (g, c) lanes in, the matching (g, o1) lanes out, the chunk
+        boundaries on whole lane tiles.  Returns the conductance-only
+        projection ``y0`` (bn rows) and the drive-dependent ``t``
+        (bm*bn rows)."""
+        c0 = _celu(g0)
+        y0, t = [], []
+        for c in range(n_chunk):
+            cl = slice(c * cin, (c + 1) * cin)
+            y0.append(_exact_dot(c0[:, cl], w))
+            t.append(gemm(_celu(by_row(v[:, cl]) + by_block(g0[:, cl]))
+                          - by_block(c0[:, cl]), w))
+        if n_chunk == 1:
+            return y0[0], t[0]
+        return jnp.concatenate(y0, axis=1), jnp.concatenate(t, axis=1)
+
     def tile(d, accs):
         # the wordline drive of the bm rows and their positive-rail mask,
         # expanded from row groups g to the (g, c) lanes of each piece
@@ -308,10 +331,8 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
             for kk in range(k1):
                 lanes = pl.ds((kk * W + w) * G, G)
                 g0 = _exact_dot(gn_ref[d, :, lanes], eg) + b0
-                c0 = _celu(g0)
-                y0 = y0 + _exact_dot(c0, w1[kk])
-                t = gemm(_celu(by_row(v0[kk]) + by_block(g0))
-                         - by_block(c0), w1[kk])
+                y1, t = stage1(v0[kk], g0, w1[kk])
+                y0 = y0 + y1
                 t_full = t if t_full is None else t_full + t
                 tp = t * mk[kk]
                 t_pos = tp if t_pos is None else t_pos + tp
@@ -359,6 +380,29 @@ def _const_spec(arr):
 def _kron_eye(n: int, w: jax.Array) -> jax.Array:
     """Block-diagonal ``kron(I_n, w)``: one copy of ``w`` per row group."""
     return jnp.kron(jnp.eye(n, dtype=jnp.float32), w.astype(jnp.float32))
+
+
+_LANES = 128                               # lanes of a vreg, of an MXU tile
+
+
+def stage1_chunk(G: int, C0: int, O1: int) -> int:
+    """Row groups one stage-1 contraction covers: the fewest that divide
+    ``G`` and fill whole lane tiles on both sides, ``Gc * C0`` channels in
+    and ``Gc * O1`` out -- so its weight ``kron(I_Gc, w)`` is the diagonal
+    block of ``kron(I_G, w)`` that holds every nonzero tile -- else ``G``,
+    one contraction over every group."""
+    return next((gc for gc in range(1, G + 1)
+                 if G % gc == 0 and gc * C0 % _LANES == 0
+                 and gc * O1 % _LANES == 0), G)
+
+
+def stage1_tile_share(G: int, C0: int, O1: int) -> float:
+    """The 128x128 weight tiles stage 1 pushes through the MXU, over those
+    of the full ``kron(I_G, w)``: 1.0 for one contraction over every
+    group."""
+    tiles = lambda n: -(-n * C0 // _LANES) * -(-n * O1 // _LANES)
+    gc = stage1_chunk(G, C0, O1)
+    return G // gc * tiles(gc) / tiles(G)
 
 
 # stacked rows (batch rows x crossbar blocks) one grid step of the unified
@@ -424,11 +468,17 @@ def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
     nbt = NOp // bn                                   # block tiles per nb
     bm, mt = row_tile(M, bn)
     Mp = bm * mt
+    Gc = stage1_chunk(G, C0, O1)
     if OBS.enabled:
+        labels = dict(m=str(M), nb=str(NB), no=str(NO))
         OBS.gauge("emulator_kernel_rows_per_pass",
                   "batch rows that share one conductance pass of the "
                   "unified emulator kernel (rows per grid step)",
-                  m=str(M), nb=str(NB), no=str(NO)).set(bm)
+                  **labels).set(bm)
+        OBS.gauge("emulator_kernel_stage1_tile_share",
+                  "128x128 weight tiles the unified emulator kernel's "
+                  "stage 1 pushes, over those of the full kron(I_G, w)",
+                  **labels).set(stage1_tile_share(G, C0, O1))
 
     # tiles d lead, blocks next, (kk, w, g) in the lanes.  The wrapper's
     # ops sit under two named scopes, so the device trace tells the
@@ -465,7 +515,7 @@ def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
         ev = _kron_eye(G, aux["w0v"][None])              # (G, G*C0)
         eg = _kron_eye(G, aux["w0g"][None])
         b0 = jnp.tile(f32(aux["b0"]), G)[None]           # (1, G*C0)
-        w1big = jnp.stack([_kron_eye(G, w1k[kk]) for kk in range(k1)])
+        w1big = jnp.stack([_kron_eye(Gc, w1k[kk]) for kk in range(k1)])
         b1 = jnp.tile(f32(aux["hstages"][0][1]), G)[None]
         em = _kron_eye(G, jnp.ones((1, O1), jnp.float32))  # mask->(g, o1)
         consts = [ev, eg, b0, w1big, b1, em]
@@ -493,6 +543,7 @@ def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
 
     out = pl.pallas_call(
         functools.partial(_unified_kernel, D=D, W=W, G=G, k1=k1, kw=kw,
+                          n_chunk=G // Gc,
                           n_tail=len(aux["hstages"]) - 1, n_fc=n_fc,
                           compute_dtype=compute_dtype),
         grid=(NB * nbt, mt),

@@ -213,10 +213,14 @@ def test_emulator_block_unified_row_tiles(geom, block_n, M):
                                **UNIFIED_F32_TOL)
 
 
-def test_emulator_kernel_rows_per_pass_gauge():
-    """With telemetry on, a launch records how many batch rows share one
-    conductance pass (set while tracing, so the output is unchanged); with
-    it off, nothing is recorded."""
+# rows that share one conductance pass; the share of kron(I_G, w)'s
+# 128x128 tiles that stage 1 pushes, at geometry A's G = 32, C0 = 16, O1 = 8
+@pytest.mark.parametrize("name,value", [
+    ("emulator_kernel_rows_per_pass", 4),
+    ("emulator_kernel_stage1_tile_share", 0.5)])
+def test_emulator_kernel_rows_per_pass_gauge(name, value):
+    """With telemetry on, a launch records its gauges (set while tracing,
+    so the output is unchanged); with it off, nothing is recorded."""
     from repro.kernels.emulator_block.emulator_block import (
         emulator_block_unified_pallas)
     from repro.obs import OBS
@@ -226,19 +230,80 @@ def test_emulator_kernel_rows_per_pass_gauge():
     assert not OBS.enabled                        # suite default
     OBS.reset()
     off = launch()
-    assert "emulator_kernel_rows_per_pass" not in OBS.snapshot()["metrics"]
+    assert name not in OBS.snapshot()["metrics"]
     OBS.enable()
     try:
         on = launch()
-        met = OBS.snapshot()["metrics"]["emulator_kernel_rows_per_pass"]
+        met = OBS.snapshot()["metrics"][name]
     finally:
         OBS.reset()
         OBS.disable()
     (s,) = met["series"]
     assert met["kind"] == "gauge"
     assert s["labels"] == {"m": "4", "nb": "2", "no": "3"}
-    assert s["value"] == 4
+    assert s["value"] == value
     np.testing.assert_array_equal(on, off)
+
+
+def _stage1_shape(geom):
+    """(G, C0, O1, w1k) of a geometry's stage 1: G row groups of C0
+    channels in, O1 out, one (C0, O1) weight per window position."""
+    from repro.core import conv4xbar
+    from repro.models.common import init_params
+    params = init_params(jax.random.PRNGKey(0),
+                         conv4xbar.conv4xbar_schema(geom, n_periph=2))
+    w1k = conv4xbar.blocklast_weights(params, geom)["w1k"]
+    k1, C0, O1 = w1k.shape
+    return geom.rows // k1, C0, O1, w1k
+
+
+@pytest.mark.parametrize("case,G,C0,O1,gc,share", [
+    (CASE_A.name, None, None, None, 16, 0.5),
+    (CASE_B.name, None, None, None, 16, 0.5),
+    # Gc * O1 never a multiple of 128 below G: one contraction
+    ("O1 = 8, G = 6", 6, 16, 8, 6, 1.0),
+    # Gc * C0 reaches a multiple of 128 only at G itself
+    ("C0 = 12, G = 32", 32, 12, 8, 32, 1.0)])
+def test_stage1_chunk(case, G, C0, O1, gc, share):
+    """Stage 1's lane chunk is the fewest row groups that divide G and
+    fill whole 128-lane tiles on both sides, else G; the share of the
+    full weight's tiles it pushes follows."""
+    from repro.kernels.emulator_block.emulator_block import (
+        stage1_chunk, stage1_tile_share)
+    if G is None:
+        geom = {CASE_A.name: CASE_A, CASE_B.name: CASE_B}[case]
+        G, C0, O1, _ = _stage1_shape(geom)
+        assert (G, C0, O1) == (32, 16, 8)
+    assert stage1_chunk(G, C0, O1) == gc
+    assert stage1_tile_share(G, C0, O1) == share
+
+
+@pytest.mark.parametrize("geom", [CASE_A, CASE_B], ids=lambda g: g.name)
+def test_stage1_chunk_weight_is_the_diagonal_block(geom):
+    """The chunk weight kron(I_Gc, w) is each diagonal block of the full
+    kron(I_G, w), and every 128x128 tile of the full weight outside those
+    blocks -- the tiles stage 1 no longer pushes -- is exactly zero."""
+    from repro.kernels.emulator_block.emulator_block import (
+        _kron_eye, stage1_chunk, stage1_tile_share)
+    G, C0, O1, w1k = _stage1_shape(geom)
+    gc = stage1_chunk(G, C0, O1)
+    ci, co = gc * C0, gc * O1
+    for kk in range(w1k.shape[0]):
+        full = np.asarray(_kron_eye(G, w1k[kk]))
+        chunk = np.asarray(_kron_eye(gc, w1k[kk]))
+        assert chunk.shape == (ci, co)
+        kept = np.zeros(full.shape, bool)
+        for c in range(G // gc):
+            np.testing.assert_array_equal(
+                full[c * ci:(c + 1) * ci, c * co:(c + 1) * co], chunk)
+            kept[c * ci:(c + 1) * ci, c * co:(c + 1) * co] = True
+        assert np.all(full[~kept] == 0.0)
+        tiles = kept.reshape(full.shape[0] // 128, 128,
+                             full.shape[1] // 128, 128).any(axis=(1, 3))
+        assert tiles.mean() == stage1_tile_share(G, C0, O1)
+        nonzero = (full != 0).reshape(tiles.shape[0], 128,
+                                      tiles.shape[1], 128).any(axis=(1, 3))
+        assert np.all(tiles[nonzero])
 
 
 def test_emulator_block_unified_conditioned():

@@ -8,6 +8,8 @@ projections of gemma3-1b and DeepSeek-Coder-33B.  The topology is
 described inside a fixture, never at import time: only one process may
 load the TPU library, and every test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -82,9 +84,26 @@ def _compile(one_chip, site, block_n, compute_dtype, arch="gemma3-1b",
     return jax.jit(fwd).lower(*args).compile()
 
 
-def _check(compiled):
+# stage 1's weight, kron(I_Gc, w1k[kk]) for the k1 = 2 window positions
+# of one lane chunk of Gc = 16 row groups: 256 (g, c) lanes in, 128 out
+STAGE1_WEIGHT = "f32[2,256,128]"
+STAGE1_OPERAND = 7                  # after u, pos, g_norm, shift, ev, eg, b0
+
+
+def _kernel_operands(text):
+    """The kernel's operand shapes, from its custom call's layout
+    constraints in the compiled HLO text."""
+    (line,) = [ln for ln in text.splitlines() if "custom-call(" in ln
+               and "emulator_block_unified" in ln]
+    cons = line.split("operand_layout_constraints={", 1)[1]
+    return re.findall(r"\w+\[[\d,]*\]", cons.split("}}", 1)[0])
+
+
+def _check(compiled, stage1_weight=None):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    if stage1_weight is not None:
+        assert _kernel_operands(text)[STAGE1_OPERAND] == stage1_weight
     # the kernel's output reaches the wrapper's relayouts through bitcasts
     # alone, so the one device op a launch that names the kernel is the
     # kernel itself (a consumer's HLO text names its operands)
@@ -118,15 +137,16 @@ def test_unified_kernel_compiles_dsc33b_f32(one_chip, site, M, block_n):
     """At DeepSeek-Coder-33B's MLP widths (7168 -> 19200 -> 7168) and the
     decode and prefill row counts of its benchmark cell, the row tile
     (``STEP_ROWS`` stacked rows a grid step) fits the default scoped
-    VMEM."""
+    VMEM, and stage 1 contracts against one lane chunk's weight."""
     _check(_compile(one_chip, site, block_n, jnp.float32,
-                    arch="deepseek-coder-33b", M=M))
+                    arch="deepseek-coder-33b", M=M), STAGE1_WEIGHT)
 
 
 @pytest.mark.parametrize("site", [(4096, 4096), (4096, 1024)],
                          ids=["q_o", "k_v"])
 def test_unified_kernel_compiles_phi35moe_attn(one_chip, site):
     """At Phi-3.5-MoE's attention widths and the 32 rows of its prefill
-    cell (eight row tiles of 4), the kernel fits and its output reaches
-    the wrapper through bitcasts, one kernel op a launch."""
-    _check(_compile(one_chip, site, 128, jnp.float32, M=32))
+    cell (eight row tiles of 4), the kernel fits, its output reaches
+    the wrapper through bitcasts, one kernel op a launch, and stage 1
+    contracts against one lane chunk's weight."""
+    _check(_compile(one_chip, site, 128, jnp.float32, M=32), STAGE1_WEIGHT)

@@ -2,7 +2,7 @@
 circuit simulator. Times one computing-block batch through:
   circuit   -- Newton-Raphson solver (SPICE stand-in)
   analytic  -- expert analytical model
-  emulator  -- Conv4Xbar (paper conv path, fused path, Pallas kernels)
+  emulator  -- Conv4Xbar (paper conv path, fused path)
 and a system-level figure: one AnalogMatmul (K=512, N=32) per backend.
 
 Besides the CSV lines, every run appends a machine-readable entry to
@@ -40,23 +40,21 @@ SMOKE = EmulatorTrainConfig(n_train=512, n_test=128, epochs=2, lr=2e-3,
 
 
 def _pallas_backend() -> str:
-    """Label the Pallas rows by how the kernel actually executes."""
+    """How the Pallas kernel executes on this backend (the run row's
+    ``pallas`` field)."""
     return "tpu" if jax.default_backend() == "tpu" else "interp"
 
 
 def run(batch: int = 2048, seed: int = 0, tcfg=QUICK, iters: int = 3,
         with_circuit: bool = True):
-    # benchmark runs sweep block sizes (kernels.autotune); the resolved
-    # configs land in the run row, and telemetry rides along so the
-    # cache-hit counters land there too (schema 3)
-    os.environ.setdefault("REPRO_AUTOTUNE", "1")
+    # telemetry rides along so the cache-hit counters land in the run row
+    # (schema 3)
     OBS.enable()
     geom, acfg, cp = CASE_A, AnalogConfig(), CircuitParams()
     res = get_emulator(geom.name, tcfg, seed)
     key = jax.random.PRNGKey(seed)
     x, periph = sample_block_inputs(key, batch, geom, acfg)
     xn = normalize_features(x, acfg)
-    pl_mode = _pallas_backend()
 
     fns = {
         "analytic": jax.jit(lambda a, p: analytic_block_response(a, cp, p)),
@@ -72,11 +70,6 @@ def run(batch: int = 2048, seed: int = 0, tcfg=QUICK, iters: int = 3,
         arg = x if name in ("circuit", "analytic") else xn
         dt, _ = timed(fn, arg, periph, iters=iters)
         rows[name] = dt / batch * 1e6          # us per block
-
-    from repro.kernels.emulator_block import emulator_block
-    dt, _ = timed(jax.jit(lambda a, p: emulator_block(res.params, a, p, geom)),
-                  xn, periph, iters=iters)
-    rows[f"emulator_pallas_{pl_mode}"] = dt / batch * 1e6
 
     # system level: one matmul through the executor
     w = jax.random.normal(key, (512, 32)) * 0.2
@@ -158,8 +151,8 @@ def run(batch: int = 2048, seed: int = 0, tcfg=QUICK, iters: int = 3,
 
 def _obs_summary() -> dict:
     """Counter totals worth tracking per run: executor cache hit/miss
-    counts and autotune resolutions by source, folded out of the full
-    telemetry snapshot (docs/observability.md)."""
+    counts, folded out of the full telemetry snapshot
+    (docs/observability.md)."""
     met = OBS.snapshot()["metrics"]
 
     def by_label(name: str, label: str) -> dict:
@@ -170,17 +163,13 @@ def _obs_summary() -> dict:
         return out
 
     return {"plan_cache": by_label("analog_plan_cache_total", "event"),
-            "state_cache": by_label("analog_state_cache_total", "event"),
-            "autotune_sources": by_label("autotune_resolutions_total",
-                                         "source")}
+            "state_cache": by_label("analog_state_cache_total", "event")}
 
 
 def write_json(rows, sys_rows, label: str, path: str = BENCH_JSON):
     """Append this run to the perf-trajectory file (schema v3: each run
-    row also records the autotuner's resolved block sizes and cache-hit
-    status under ``kernels``, plus the telemetry counter summary under
-    ``obs``; see docs/performance.md)."""
-    from repro.kernels import autotune
+    row also records the telemetry counter summary under ``obs``; see
+    docs/performance.md)."""
     doc = {"schema": 3, "unit_block": "us_per_block",
            "unit_matmul": "us_per_matmul_512x32_b16", "runs": []}
     if os.path.exists(path):
@@ -199,7 +188,6 @@ def write_json(rows, sys_rows, label: str, path: str = BENCH_JSON):
         "pallas": _pallas_backend(),
         "block_us": {k: round(v, 3) for k, v in rows.items()},
         "matmul_us": {k: round(v, 1) for k, v in sys_rows.items()},
-        "kernels": autotune.report(),
         "obs": _obs_summary(),
     })
     with open(path, "w") as f:

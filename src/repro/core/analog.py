@@ -22,8 +22,7 @@ the batch axis.  The emulator evaluation goes through ONE dispatcher
 (``kernels.emulator_block.emulator_block_unified``): a single fused Pallas
 kernel on TPU (both rails, both GEMM stages, scenario epilogue -- one
 compiled launch for every device corner) or the identical chunked XLA
-schedule (``apply_blocklast``) elsewhere, with block sizes resolved by
-``kernels.autotune``.
+schedule (``apply_blocklast``) elsewhere.
 
 Deployment model (docs/api.md): everything that distinguishes a deployed
 device from the ideal hardware -- perturbed conductances, read sigma and
@@ -262,8 +261,7 @@ class AnalogExecutor:
                  cp: Optional[CircuitParams] = None,
                  emulator_params: Optional[dict] = None,
                  calibration: Optional[Dict[str, tuple]] = None,
-                 fused_emulator: bool = True, fast_path: bool = True,
-                 fast_chunk: Optional[int] = None,
+                 fast_path: bool = True,
                  use_pallas: Optional[bool] = None,
                  scenario: Optional[Scenario] = None,
                  scenario_key: Optional[jax.Array] = None,
@@ -275,9 +273,7 @@ class AnalogExecutor:
         self._base_params = emulator_params
         self.calibration: Dict[str, tuple] = (
             calibration if calibration is not None else {})
-        self.fused_emulator = fused_emulator  # apply_fused vs apply (slow path)
         self.fast_path = fast_path            # cached-plan blockified path
-        self.fast_chunk = fast_chunk          # None = autotuned/heuristic
         self.use_pallas = use_pallas          # None = auto (TPU only)
         # tensor-parallel serving (repro.parallel.sharding; docs/parallel.md):
         # a (data, model) mesh shards batch rows and the tile lattice; the
@@ -679,10 +675,8 @@ class AnalogExecutor:
             params = self.emulator_params if eparams is None else eparams
             assert params is not None, \
                 "emulator backend needs trained params (core.emulator)"
-            ap = (conv4xbar.apply_fused if self.fused_emulator
-                  else conv4xbar.apply)
-            return lambda x, p: ap(params,
-                                   normalize_features(x, self.acfg), p)
+            return lambda x, p: conv4xbar.apply_fused(
+                params, normalize_features(x, self.acfg), p)
         raise ValueError(b)
 
     def block_outputs(self, x: jax.Array,
@@ -882,8 +876,7 @@ class AnalogExecutor:
                     s = s.reshape(-1, s.shape[-1])
                 y2 = emulator_block_unified(
                     laux, lp.g_norm, u, pos, shift=s,
-                    use_pallas=self.use_pallas, chunk=self.fast_chunk,
-                    tune=False)
+                    use_pallas=self.use_pallas)
                 Ml = u.shape[0]
                 asm = lambda o: o.reshape(Ml, nb_l, no_l * no).sum(axis=1)
                 return _combine(asm(y2[0]) - asm(y2[1]), Ml)
@@ -1017,8 +1010,7 @@ class AnalogExecutor:
             pos = plan.tile_v((x2d > 0).astype(jnp.float32), 1.0)
             y2 = emulator_block_unified(aux, plan.g_norm, u, pos,
                                         shift=shift, pre=pre,
-                                        use_pallas=self.use_pallas,
-                                        chunk=self.fast_chunk)
+                                        use_pallas=self.use_pallas)
             return plan.assemble(y2[0]) - plan.assemble(y2[1]), x_scale
         rails = jnp.concatenate([jnp.clip(x2d, 0.0, None),
                                  jnp.clip(-x2d, 0.0, None)], axis=0)

@@ -1,2 +1,2 @@
 from repro.kernels.emulator_block.ops import (  # noqa: F401
-    emulator_block, emulator_block_grid, emulator_block_unified)
+    emulator_block_unified)

@@ -1,226 +1,28 @@
-"""Pallas TPU kernel: the whole Conv4Xbar emulator evaluated per crossbar
-block, fused in VMEM.
+"""Pallas TPU kernel: the Conv4Xbar emulator's serving math, fused in VMEM.
 
-At system level the emulator runs over THOUSANDS of blocks per layer
-(every weight tile of every projection); the hot loop is thousands of tiny
-convs + FC stacks. This kernel keeps one batch-tile of blocks resident in
-VMEM and evaluates the full network (conv stages as blocked matmuls over
-row groups, then the FC head) without touching HBM in between -- the
-emulator's weights (a few KB) stay resident across the whole grid.
+At system level the emulator runs over THOUSANDS of crossbar blocks per
+layer (every weight tile of every projection), each a small conv + FC
+stack, for both voltage rails.  ``emulator_block_unified_pallas`` is one
+``pallas_call`` per matmul, for every device corner: each grid step
+holds one tile of crossbar blocks and one tile of batch rows in VMEM and
+evaluates both rails through the whole network without touching HBM in
+between; the emulator's weights (a few KB, as block-diagonal
+``kron(I, w)`` contractions) stay resident across the grid.
 
-Tiling: grid (N / bn); every stage is a dot over (C_in x k) contractions.
+Tiling: grid (block tiles, row tiles); ``row_tile`` sizes the row tile
+and ``stage1_chunk`` the lane chunks of stage 1.
 """
 from __future__ import annotations
 
 import functools
-from typing import List
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.conv4xbar import ConvStage, conv_out_sizes
 from repro.obs import OBS
 
 
-def _stage_apply(h, w, b, st: ConvStage):
-    """h: (n, C, D, H, W) fp32; w: (O, I, kd, kh, kw); matches apply_fused."""
-    n, C, D, H, W = h.shape
-    O = w.shape[0]
-    kd, kh, kw = st.kernel
-    if (kh, kw) == (1, 1):
-        y = jnp.einsum("ncdhw,oc->nodhw", h, w[:, :, 0, 0, 0])
-    elif kw == 1:
-        hg = h.reshape(n, C, D, H // kh, kh, W)
-        y = jnp.einsum("ncdgkw,ock->nodgw", hg, w[:, :, 0, :, 0])
-    else:
-        wk = w[:, :, 0, 0, :]
-        if st.stride[2] == kw:
-            hg = h.reshape(n, C, D, H, W // kw, kw)
-            y = jnp.einsum("ncdhgk,ock->nodhg", hg, wk)
-        else:
-            y = (jnp.einsum("ncdhw,oc->nodhw", h[..., :-1], wk[:, :, 0])
-                 + jnp.einsum("ncdhw,oc->nodhw", h[..., 1:], wk[:, :, 1]))
-    return jax.nn.celu(y + b[None, :, None, None, None])
-
-
-def _kernel(*refs, stages: List[ConvStage], n_fc: int, out_dtype):
-    # refs: x, periph, conv_w..., conv_b..., fc_w..., fc_b..., out
-    x_ref, periph_ref = refs[0], refs[1]
-    idx = 2
-    conv = []
-    for _ in stages:
-        conv.append((refs[idx], refs[idx + 1]))
-        idx += 2
-    fcs = []
-    for _ in range(n_fc):
-        fcs.append((refs[idx], refs[idx + 1]))
-        idx += 2
-    o_ref = refs[idx]
-
-    h = x_ref[...].astype(jnp.float32)
-    for (w_ref, b_ref), st in zip(conv, stages):
-        h = _stage_apply(h, w_ref[...].astype(jnp.float32),
-                         b_ref[...].astype(jnp.float32), st)
-    h = h.reshape(h.shape[0], -1)
-    p = periph_ref[...].astype(jnp.float32)
-    h = jnp.concatenate([h, p], axis=-1)
-    for i, (w_ref, b_ref) in enumerate(fcs):
-        h = jnp.dot(h, w_ref[...].astype(jnp.float32),
-                    preferred_element_type=jnp.float32) \
-            + b_ref[...].astype(jnp.float32)
-        if i < n_fc - 1:
-            h = jax.nn.celu(h)
-    o_ref[...] = h.astype(out_dtype)
-
-
-def _weight_operands(params: dict, stages: List[ConvStage], n_fc: int):
-    """Emulator weights as pallas operands with grid-constant BlockSpecs."""
-    operands, in_specs = [], []
-    names = [f"conv{j}" for j in range(len(stages))] + \
-            [f"fc{j}" for j in range(n_fc)]
-    for name in names:
-        for suf in ("_w", "_b"):
-            wgt = params[f"{name}{suf}"]
-            operands.append(wgt)
-            in_specs.append(pl.BlockSpec(
-                wgt.shape, lambda *_, nd=wgt.ndim: (0,) * nd))
-    return operands, in_specs
-
-
-def _grid_kernel(*refs, stages: List[ConvStage], n_fc: int, n_periph: int,
-                 out_dtype):
-    """2-D grid step: one batch tile of one crossbar block.
-
-    The conductance features are batch-constant, so they arrive as a
-    block-indexed operand (g_ref) shared across the whole batch axis of the
-    grid instead of a batch-broadcast tensor in HBM; the (V, G) channel
-    stack is materialized only in VMEM."""
-    v_ref, g_ref = refs[0], refs[1]
-    idx = 2
-    conv = []
-    for _ in stages:
-        conv.append((refs[idx], refs[idx + 1]))
-        idx += 2
-    fcs = []
-    for _ in range(n_fc):
-        fcs.append((refs[idx], refs[idx + 1]))
-        idx += 2
-    o_ref = refs[idx]
-
-    v = v_ref[...].astype(jnp.float32)                # (bm, 1, D, H)
-    g = g_ref[...].astype(jnp.float32)                # (1, D, H, W)
-    bm = v.shape[0]
-    D, H, W = g.shape[1], g.shape[2], g.shape[3]
-    vch = jnp.broadcast_to(v.reshape(bm, D, H, 1), (bm, D, H, W))
-    gch = jnp.broadcast_to(g, (bm, D, H, W))
-    h = jnp.stack([vch, gch], axis=1)                 # (bm, 2, D, H, W)
-    for (w_ref, b_ref), st in zip(conv, stages):
-        h = _stage_apply(h, w_ref[...].astype(jnp.float32),
-                         b_ref[...].astype(jnp.float32), st)
-    h = h.reshape(bm, -1)
-    if n_periph:
-        # serving-path peripheral features are the constant (gain=1, off=0)
-        p = jnp.concatenate([jnp.ones((bm, 1), jnp.float32),
-                             jnp.zeros((bm, n_periph - 1), jnp.float32)],
-                            axis=-1)
-        h = jnp.concatenate([h, p], axis=-1)
-    for i, (w_ref, b_ref) in enumerate(fcs):
-        h = jnp.dot(h, w_ref[...].astype(jnp.float32),
-                    preferred_element_type=jnp.float32) \
-            + b_ref[...].astype(jnp.float32)
-        if i < n_fc - 1:
-            h = jax.nn.celu(h)
-    o_ref[...] = h.reshape(bm, 1, -1).astype(out_dtype)
-
-
-def emulator_block_grid_pallas(params: dict, v01: jax.Array,
-                               g_norm: jax.Array, stages: List[ConvStage],
-                               *, block_m: int = 128,
-                               interpret: bool = False) -> jax.Array:
-    """Batched serving variant over a 2-D grid (batch tiles, NB*NO blocks).
-
-    v01: (M, NB, D, H) normalized wordline voltages; g_norm: (NB*NO, D, H, W)
-    normalized conductance features shared by every batch row.
-    Returns (M, NB*NO, O)."""
-    M, NB, D, H = v01.shape
-    NBLK = g_norm.shape[0]
-    NO = NBLK // NB
-    assert NO * NB == NBLK, (NB, NBLK)
-    n_fc = len([k for k in params if k.startswith("fc") and k.endswith("_w")])
-    n_out = params[f"fc{n_fc-1}_w"].shape[1]
-    d, h, w = conv_out_sizes(stages, D, H, g_norm.shape[-1])
-    flat = stages[-1].c_out * d * h * w
-    n_periph = params["fc0_w"].shape[0] - flat
-
-    bm = min(block_m, M)
-    padM = (-M) % bm
-    vp = jnp.pad(v01, ((0, padM), (0, 0), (0, 0), (0, 0))) if padM else v01
-    Mp = M + padM
-
-    operands = [vp, g_norm]
-    in_specs = [
-        pl.BlockSpec((bm, 1, D, H), lambda i, j: (i, j // NO, 0, 0)),
-        pl.BlockSpec((1,) + g_norm.shape[1:], lambda i, j: (j, 0, 0, 0)),
-    ]
-    w_ops, w_specs = _weight_operands(params, stages, n_fc)
-    operands += w_ops
-    in_specs += w_specs
-
-    out = pl.pallas_call(
-        functools.partial(_grid_kernel, stages=stages, n_fc=n_fc,
-                          n_periph=n_periph, out_dtype=v01.dtype),
-        grid=(Mp // bm, NBLK),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, 1, n_out), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((Mp, NBLK, n_out), v01.dtype),
-        interpret=interpret,
-    )(*operands)
-    return out[:M] if padM else out
-
-
-def emulator_block_pallas(params: dict, x: jax.Array, periph: jax.Array,
-                          stages: List[ConvStage], *, block_n: int = 256,
-                          interpret: bool = False) -> jax.Array:
-    """x: (N, C, D, H, W) normalized features; periph: (N, P) -> (N, O).
-
-    Non-divisible batches are padded to the block size and sliced back
-    (zero rows are valid block inputs), like the grid variant pads M."""
-    N = x.shape[0]
-    bn = min(block_n, N)
-    padN = (-N) % bn
-    if padN:
-        x = jnp.pad(x, ((0, padN),) + ((0, 0),) * (x.ndim - 1))
-        periph = jnp.pad(periph, ((0, padN), (0, 0)))
-    Np = N + padN
-    n_fc = len([k for k in params if k.startswith("fc") and k.endswith("_w")])
-    n_out = params[f"fc{n_fc-1}_w"].shape[1]
-
-    operands = [x, periph]
-    in_specs = [
-        pl.BlockSpec((bn,) + x.shape[1:],
-                     lambda i: (i,) + (0,) * (x.ndim - 1)),
-        pl.BlockSpec((bn, periph.shape[1]), lambda i: (i, 0)),
-    ]
-    w_ops, w_specs = _weight_operands(params, stages, n_fc)
-    operands += w_ops
-    in_specs += w_specs
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, stages=stages, n_fc=n_fc,
-                          out_dtype=x.dtype),
-        grid=(Np // bn,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bn, n_out), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Np, n_out), x.dtype),
-        interpret=interpret,
-    )(*operands)
-    return out[:N] if padN else out
-
-
-# --------------------------------------------------------------------------- #
-# THE unified serving kernel: one pallas_call for every device corner
-# --------------------------------------------------------------------------- #
 def _celu(x):
     """CELU (alpha 1) through ``exp``: Mosaic has no ``expm1`` lowering,
     so the negative branch carries an absolute error of about one f32
@@ -240,7 +42,7 @@ _NT = (((1,), (1,)), ((), ()))             # a @ b.T
 
 
 def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
-                    n_chunk: int, n_tail: int, n_fc: int, compute_dtype):
+                    n_chunk: int, n_tail: int, n_fc: int):
     """Grid step (block tile j, row tile i): BOTH rails of the dual-rail
     delta factorization and the whole conv/FC stack for ``bn`` crossbar
     blocks and ``bm`` batch rows, in VMEM.
@@ -277,14 +79,6 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
     n_out = o_ref.shape[3] // 2
     bm, bn = u_ref.shape[3], gn_ref.shape[1]
 
-    if compute_dtype == jnp.float32:
-        gemm = _exact_dot
-    else:
-        def gemm(a, b, dims=(((1,), (0,)), ((), ()))):
-            return jax.lax.dot_general(
-                a.astype(compute_dtype), b.astype(compute_dtype), dims,
-                preferred_element_type=jnp.float32)
-
     def by_block(a):
         """(bn, L), one row per block -> (bm*bn, L), the same for each m."""
         return jnp.broadcast_to(a[None], (bm,) + a.shape).reshape(
@@ -313,8 +107,9 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
         for c in range(n_chunk):
             cl = slice(c * cin, (c + 1) * cin)
             y0.append(_exact_dot(c0[:, cl], w))
-            t.append(gemm(_celu(by_row(v[:, cl]) + by_block(g0[:, cl]))
-                          - by_block(c0[:, cl]), w))
+            t.append(_exact_dot(
+                _celu(by_row(v[:, cl]) + by_block(g0[:, cl]))
+                - by_block(c0[:, cl]), w))
         if n_chunk == 1:
             return y0[0], t[0]
         return jnp.concatenate(y0, axis=1), jnp.concatenate(t, axis=1)
@@ -340,7 +135,7 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
             for r, pre in enumerate((y0 + t_pos, y0 + t_full - t_pos)):
                 x = _celu(pre)
                 for wk_ref, bk_ref in tail:
-                    x = _celu(gemm(x, wk_ref[...]) + bk_ref[...])
+                    x = _celu(_exact_dot(x, wk_ref[...]) + bk_ref[...])
                 h[r].append(x)
         # bitline-pair stage, then this tile's rows of fc0
         out = []
@@ -349,8 +144,8 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
             for wo in range(wo_n):
                 s = wsb_ref[...]
                 for j in range(kw):
-                    s = s + gemm(h[r][wo * kw + j], ws_ref[j])
-                acc = acc + gemm(_celu(s), f0_ref[d * wo_n + wo])
+                    s = s + _exact_dot(h[r][wo * kw + j], ws_ref[j])
+                acc = acc + _exact_dot(_celu(s), f0_ref[d * wo_n + wo])
             out.append(acc)
         return tuple(out)
 
@@ -360,7 +155,7 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
     for r in range(2):
         x = accs[r]
         for fw_ref, fb_ref in fcs[:-1]:
-            x = gemm(_celu(x), fw_ref[...]) + fb_ref[...]
+            x = _exact_dot(_celu(x), fw_ref[...]) + fb_ref[...]
         # the last layer is contracted transposed, a row at a time,
         # (n_out, bn): blocks land in the lanes, so the output is not
         # padded to 128 lanes, and with the rows leading the output block
@@ -368,7 +163,8 @@ def _unified_kernel(*refs, D: int, W: int, G: int, k1: int, kw: int,
         fw_ref, fb_ref = fcs[-1]
         x = _celu(x)
         for m in range(bm):
-            y = gemm(fw_ref[...], x[m * bn:(m + 1) * bn], _NT) + fb_ref[...]
+            y = (_exact_dot(fw_ref[...], x[m * bn:(m + 1) * bn], _NT)
+                 + fb_ref[...])
             o_ref[0, 0, m, r * n_out:(r + 1) * n_out, :] = y.astype(
                 o_ref.dtype)
 
@@ -405,6 +201,9 @@ def stage1_tile_share(G: int, C0: int, O1: int) -> float:
     return G // gc * tiles(gc) / tiles(G)
 
 
+# crossbar blocks a grid step: the tiling every served launch has run at,
+# and the one the chip's bundle dumps and timings measured
+BLOCK_N = 128
 # stacked rows (batch rows x crossbar blocks) one grid step of the unified
 # kernel holds: at the served widths its working set then fits the v5e's
 # default scoped VMEM (tests/test_tpu_compile.py)
@@ -422,9 +221,8 @@ def row_tile(M: int, bn: int) -> tuple[int, int]:
 def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
                                   u01: jax.Array, pos01: jax.Array, *,
                                   shift: jax.Array | None = None,
-                                  block_n: int = 128,
-                                  interpret: bool = False,
-                                  compute_dtype=jnp.float32) -> jax.Array:
+                                  block_n: int = BLOCK_N,
+                                  interpret: bool = False) -> jax.Array:
     """One kernel launch per matmul, every corner on the TPU path.
 
     aux: ``conv4xbar.blocklast_weights`` tensors; g_norm: (NB, NO, D, H, W)
@@ -444,10 +242,8 @@ def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
     differently -- the stage-1 projection of the zero-voltage response
     is summed per window position, and the conv/FC stack runs as
     block-diagonal contractions -- so the two agree to f32 rounding, not
-    bitwise (tests/test_kernels.py states the tolerance).  f32 GEMMs run
-    at ``Precision.HIGHEST``; ``compute_dtype=bfloat16`` runs the GEMM
-    stages with bf16 operands and f32 accumulation, while the drive and
-    conductance expansions and the zero-voltage projection stay exact.
+    bitwise (tests/test_kernels.py states the tolerance).  Every
+    contraction is f32 at ``Precision.HIGHEST``.
     Returns (2, M*NB*NO, O) rail block outputs, row-compatible with
     ``apply_blocklast``."""
     M, NB, D, H = u01.shape
@@ -544,8 +340,7 @@ def emulator_block_unified_pallas(aux: dict, g_norm: jax.Array,
     out = pl.pallas_call(
         functools.partial(_unified_kernel, D=D, W=W, G=G, k1=k1, kw=kw,
                           n_chunk=G // Gc,
-                          n_tail=len(aux["hstages"]) - 1, n_fc=n_fc,
-                          compute_dtype=compute_dtype),
+                          n_tail=len(aux["hstages"]) - 1, n_fc=n_fc),
         grid=(NB * nbt, mt),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, bm, 2 * n_out, bn),

@@ -20,8 +20,8 @@ from repro.configs.rram_ps32 import CASE_A
 from repro.core import conv4xbar
 from repro.kernels.emulator_block.emulator_block import (
     emulator_block_unified_pallas)
-from repro.kernels.emulator_block.ops import BLOCK_N_CANDIDATES
 from repro.models.common import init_params
+from repro.nonideal import N_SCENARIO_FEATURES
 
 V5E_HBM_BYTES = 16 * 1024 ** 3
 M_PREFILL = 32                     # batch 2 x prompt 16 rows per matmul
@@ -57,30 +57,38 @@ def one_chip(topo):
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-def _compile(one_chip, site, block_n, compute_dtype, arch="gemma3-1b",
-             M=M_PREFILL):
-    """``site``: an MLP site of ``arch`` by name, or its (K, N)."""
+def _compile(one_chip, site, corner="ideal", arch="gemma3-1b", M=M_PREFILL):
+    """``site``: an MLP site of ``arch`` by name, or its (K, N).
+    ``corner``: ``ideal`` (no shift), or a conditioned net's fc0 shift
+    ``sfeat @ f0_scen`` -- ``flat`` for a whole-plan corner (a grid-constant
+    operand), ``tiled`` for per-tile features (one row a block, the
+    block-indexed operand the stressed corner runs)."""
     K, N = site if isinstance(site, tuple) else _mlp_shapes(arch)[site]
     g = CASE_A
     blk_in = g.tiles * g.rows
     NB, NO = -(-K // blk_in), N // g.outputs
-    schema = conv4xbar.conv4xbar_schema(g, n_periph=2)
+    n_periph = 2 if corner == "ideal" else 2 + N_SCENARIO_FEATURES
+    schema = conv4xbar.conv4xbar_schema(g, n_periph=n_periph)
     eparams = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0),
                                                  schema))
 
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def fwd(ep, gn, u, pos):
+    def fwd(ep, gn, u, pos, *sfeat):
         aux = conv4xbar.blocklast_weights(ep, g)
-        return emulator_block_unified_pallas(
-            aux, gn, u, pos, block_n=block_n, interpret=False,
-            compute_dtype=compute_dtype)
+        shift = sfeat[0] @ aux["f0_scen"] if sfeat else None
+        return emulator_block_unified_pallas(aux, gn, u, pos, shift=shift,
+                                             interpret=False)
 
     args = (jax.tree.map(lambda a: sds(a.shape, a.dtype), eparams),
             sds((NB, NO, g.tiles, g.rows, g.cols)),
             sds((M, NB, g.tiles, g.rows)),
             sds((M, NB, g.tiles, g.rows)))
+    if corner == "flat":
+        args += (sds((N_SCENARIO_FEATURES,)),)
+    elif corner == "tiled":
+        args += (sds((NB * NO, N_SCENARIO_FEATURES)),)
     return jax.jit(fwd).lower(*args).compile()
 
 
@@ -117,29 +125,24 @@ def _check(compiled, stage1_weight=None):
 
 
 @pytest.mark.parametrize("site", ["gate_up", "down"])
-@pytest.mark.parametrize("block_n", BLOCK_N_CANDIDATES)
-def test_unified_kernel_compiles_f32(one_chip, site, block_n):
-    """Every block size the autotuner may pick compiles for the v5e at
-    the served widths, and the kernel is a Mosaic custom call."""
-    _check(_compile(one_chip, site, block_n, jnp.float32))
-
-
-def test_unified_kernel_compiles_bf16(one_chip):
-    """The bf16-operand variant compiles at the gate/up width."""
-    _check(_compile(one_chip, "gate_up", BLOCK_N_CANDIDATES[0],
-                    jnp.bfloat16))
+@pytest.mark.parametrize("corner", ["ideal", "flat", "tiled"])
+def test_unified_kernel_compiles_f32(one_chip, site, corner):
+    """At the served widths the kernel compiles for the v5e at every
+    corner's shift operand, and is a Mosaic custom call."""
+    _check(_compile(one_chip, site, corner))
 
 
 @pytest.mark.parametrize("site", ["gate_up", "down"])
 @pytest.mark.parametrize("M", [4, 8])
-@pytest.mark.parametrize("block_n", BLOCK_N_CANDIDATES)
-def test_unified_kernel_compiles_dsc33b_f32(one_chip, site, M, block_n):
+@pytest.mark.parametrize("corner", ["ideal", "tiled"])
+def test_unified_kernel_compiles_dsc33b_f32(one_chip, site, M, corner):
     """At DeepSeek-Coder-33B's MLP widths (7168 -> 19200 -> 7168) and the
     decode and prefill row counts of its benchmark cell, the row tile
     (``STEP_ROWS`` stacked rows a grid step) fits the default scoped
-    VMEM, and stage 1 contracts against one lane chunk's weight."""
-    _check(_compile(one_chip, site, block_n, jnp.float32,
-                    arch="deepseek-coder-33b", M=M), STAGE1_WEIGHT)
+    VMEM, with the ideal corner's shift or a per-tile one, and stage 1
+    contracts against one lane chunk's weight."""
+    _check(_compile(one_chip, site, corner, arch="deepseek-coder-33b", M=M),
+           STAGE1_WEIGHT)
 
 
 @pytest.mark.parametrize("site", [(4096, 4096), (4096, 1024)],
@@ -149,4 +152,4 @@ def test_unified_kernel_compiles_phi35moe_attn(one_chip, site):
     cell (eight row tiles of 4), the kernel fits, its output reaches
     the wrapper through bitcasts, one kernel op a launch, and stage 1
     contracts against one lane chunk's weight."""
-    _check(_compile(one_chip, site, 128, jnp.float32, M=32), STAGE1_WEIGHT)
+    _check(_compile(one_chip, site, M=32), STAGE1_WEIGHT)

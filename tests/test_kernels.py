@@ -220,10 +220,12 @@ def test_emulator_block_unified_row_tiles(geom, block_n, M):
 
 
 # rows that share one conductance pass; the share of kron(I_G, w)'s
-# 128x128 tiles that stage 1 pushes, at geometry A's G = 32, C0 = 16, O1 = 8
+# 128x128 tiles that stage 1 pushes, at geometry A's G = 32, C0 = 16, O1 = 8;
+# the tail's rail x bitline pieces a pass, four 32-lane pieces
 @pytest.mark.parametrize("name,value", [
     ("emulator_kernel_rows_per_pass", 4),
-    ("emulator_kernel_stage1_tile_share", 0.5)])
+    ("emulator_kernel_stage1_tile_share", 0.5),
+    ("emulator_kernel_tail_pieces_per_pass", 4)])
 def test_emulator_kernel_rows_per_pass_gauge(name, value):
     """With telemetry on, a launch records its gauges (set while tracing,
     so the output is unchanged); with it off, nothing is recorded."""
@@ -310,6 +312,123 @@ def test_stage1_chunk_weight_is_the_diagonal_block(geom):
         nonzero = (full != 0).reshape(tiles.shape[0], 128,
                                       tiles.shape[1], 128).any(axis=(1, 3))
         assert np.all(tiles[nonzero])
+
+
+def _tail_fixture(geom):
+    """aux of a geometry's net, and (D, G, W, kw) of its tail."""
+    from repro.core import conv4xbar
+    from repro.models.common import init_params
+    params = init_params(jax.random.PRNGKey(0),
+                         conv4xbar.conv4xbar_schema(geom, n_periph=2))
+    aux = conv4xbar.blocklast_weights(params, geom)
+    G = geom.rows // aux["w1k"].shape[0]
+    return aux, geom.tiles, G, geom.cols, aux["wstage"][2]
+
+
+@pytest.mark.parametrize("case,width,W,kw,pieces", [
+    # both geometries: 32-lane pieces, four a pass (A: all of a tile's
+    # pieces; B: 16 pieces a tile d in 4 passes)
+    (CASE_A.name, None, None, None, 4),
+    (CASE_B.name, None, None, None, 4),
+    # two fit: half a window of both rails, a whole window of one rail
+    ("width 48", 48, 2, 2, 2),
+    # wider than 64 lanes: one piece a pass, the unpacked schedule
+    ("width 80", 80, 2, 2, 1),
+    ("width 160", 160, 8, 2, 1),
+    # three fit, but a pass of 3 would split a window: 2
+    ("width 40, W 3", 40, 3, 2, 2)])
+def test_tail_pieces_per_pass(case, width, W, kw, pieces):
+    """Pieces a tail pass carries: the most that fit 128 lanes, divide
+    the 2 * W pieces, and hold whole windows of both rails or divide
+    one."""
+    from repro.kernels.emulator_block.emulator_block import (
+        tail_pieces_per_pass)
+    if width is None:
+        geom = {CASE_A.name: CASE_A, CASE_B.name: CASE_B}[case]
+        aux, _, G, W, kw = _tail_fixture(geom)
+        (w2, _, k2), (w3, _, k3) = aux["hstages"][1:]
+        width = max(G // k2 * w2.shape[1], G // k2 // k3 * w3.shape[1])
+        assert width == 32
+    assert tail_pieces_per_pass(width, W, kw) == pieces
+
+
+def _assert_blocks(a, rows, cols, blocks):
+    """``a`` holds each ``blocks[(i, j)]`` at block row i and column j of
+    ``rows`` x ``cols`` blocks, and exact zeros everywhere else."""
+    a = np.asarray(a)
+    kept = np.zeros(a.shape, bool)
+    for (i, j), b in blocks.items():
+        sl = np.s_[i * rows:(i + 1) * rows, j * cols:(j + 1) * cols]
+        np.testing.assert_array_equal(a[sl], np.asarray(b, np.float32))
+        kept[sl] = True
+    assert np.all(a[~kept] == 0.0)
+
+
+@pytest.mark.parametrize("geom", [CASE_A, CASE_B], ids=lambda g: g.name)
+def test_tail_operands_are_block_placements(geom):
+    """Each packed tail weight holds the per-piece weight on its blocks
+    and zeros elsewhere: stage 2's copy i writes piece i's 32 lanes
+    [32 i, 32 i + 32), stage 3 is kron(I_P, w3), the bitline-pair stage
+    kron(I_2, ws) over both rails' windows, fc0 each window's tile rows
+    in its rail's lanes, and the FC layers kron(I_2, w)."""
+    from repro.kernels.emulator_block.emulator_block import (
+        _kron_eye, tail_operands)
+    aux, D, G, W, kw = _tail_fixture(geom)
+    P, ops = tail_operands(aux, D, G, W)
+    assert P == 4
+    (w2p, b2p, w3p, b3p, wsp, wsb, f0p, f0b, f1, f1b, fl, flb) = ops
+    (w2, b2, k2), (w3, b3, _) = aux["hstages"][1:]
+    w2g = _kron_eye(G // k2, w2)                      # (256, 32)
+    assert w2g.shape == (256, 32) and w2p.shape == (P, 256, P * 32)
+    for i in range(P):
+        _assert_blocks(w2p[i], 256, 32, {(0, i): w2g})
+    _assert_blocks(w3p, 32, 32, {(i, i): w3 for i in range(P)})
+    np.testing.assert_array_equal(b2p[0], np.tile(b2, P * G // k2))
+    np.testing.assert_array_equal(b3p[0], np.tile(b3, P))
+    ws, wsb0, _ = aux["wstage"]
+    assert wsp.shape == (1, P * 32, 64)
+    _assert_blocks(wsp[0], 2 * 32, 32, {(0, 0): ws, (1, 1): ws})
+    np.testing.assert_array_equal(wsb[0], np.tile(wsb0, 2))
+    (f0, f0b0), (fw1, fb1), (fwl, fbl) = aux["fcs"]
+    wo_n = W // kw
+    f0 = np.asarray(f0).reshape(D, wo_n, 32, 32)
+    assert f0p.shape == (D * wo_n, 64, 64)
+    for d in range(D):
+        for wo in range(wo_n):
+            _assert_blocks(f0p[d * wo_n + wo], 32, 32,
+                           {(0, 0): f0[d, wo], (1, 1): f0[d, wo]})
+    np.testing.assert_array_equal(f0b[0], np.tile(f0b0, 2))
+    _assert_blocks(f1, 32, 16, {(0, 0): fw1, (1, 1): fw1})
+    _assert_blocks(fl, geom.outputs, 16, {(0, 0): fwl.T, (1, 1): fwl.T})
+    np.testing.assert_array_equal(f1b[0], np.tile(fb1, 2))
+    np.testing.assert_array_equal(flb[:, 0], np.tile(fbl, 2))
+
+
+@pytest.mark.parametrize("geom", [CASE_A, CASE_B], ids=lambda g: g.name)
+# one piece a pass (the unpacked schedule: a bitline-pair contraction
+# reads two passes) and two (one rail's window a pass)
+@pytest.mark.parametrize("pieces", [1, 2])
+def test_emulator_block_unified_any_tail_packing(geom, pieces,
+                                                 monkeypatch):
+    """The kernel reads the same at every packing of the tail, not only
+    the four pieces a pass both geometries take: forced to 1 or 2, it
+    matches the XLA path within UNIFIED_F32_TOL under a block-indexed
+    shift."""
+    from repro.core import conv4xbar
+    from repro.kernels.emulator_block import emulator_block as eb
+    from repro.nonideal import N_SCENARIO_FEATURES
+    aux, g, pre, u, pos = _unified_fixture(
+        geom, n_periph=2 + N_SCENARIO_FEATURES, M=3)
+    shift = jax.random.uniform(jax.random.PRNGKey(4),
+                               (2 * 3, N_SCENARIO_FEATURES)) @ aux["f0_scen"]
+    monkeypatch.setattr(eb, "tail_pieces_per_pass", lambda *_: pieces)
+    assert eb.tail_operands(aux, geom.tiles, 32, geom.cols)[0] == pieces
+    out = eb.emulator_block_unified_pallas(aux, g, u, pos, shift=shift,
+                                           block_n=8, interpret=True)
+    ref = conv4xbar.apply_blocklast(aux, pre, u, pos, chunk=2,
+                                    fc0_shift=shift)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               **UNIFIED_F32_TOL)
 
 
 def test_emulator_block_unified_conditioned():
